@@ -60,11 +60,12 @@ def main():
                          "dispatches convs + head through the "
                          "kernels/pruned_matmul block-skip Pallas kernel so "
                          "device FLOPs track retention (requires --engine "
-                         "masked; interpret-mode off-TPU)")
+                         "masked; interpret mode on CPU)")
     ap.add_argument("--compute-blocks", default="128,128,128",
                     metavar="BM,BN,BK",
-                    help="pruned_matmul tile sizes; shrink (e.g. 128,8,8) "
-                         "for fine-grained CPU/interpret runs")
+                    help="pruned_matmul tile sizes: multiples of 128 on "
+                         "TPU; smaller (e.g. 128,8,8) only for fine-grained "
+                         "CPU/interpret runs")
     ap.add_argument("--mesh-devices", type=int, default=0, metavar="N",
                     help="mesh-sharded fleet: shard the [W, ...] stacks over "
                          "N devices (fused sync engine only; W %% N == 0). "

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding.compat import shard_map_compat
-
 from .aggregation import UnitMap
 from .masks import GlobalIndex
 
@@ -116,9 +114,10 @@ def collab_round(
 
     pspec_rep = jax.tree.map(lambda _: P(), global_params)
     pspec_masks = jax.tree.map(lambda _: P(axis), masks)
-    return shard_map_compat(
+    return jax.shard_map(
         worker,
         mesh=mesh,
         in_specs=(pspec_rep, pspec_masks, P(axis), P(axis)),
         out_specs=pspec_rep,
+        check_vma=False,
     )(global_params, masks, x, y)

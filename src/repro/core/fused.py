@@ -99,10 +99,9 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.models.cnn import cnn_flops_from_shapes, extract_bn_scales
-from repro.sharding.compat import shard_map_compat
 from repro.sharding.specs import fleet_sharding
 
 from repro.optim.group_lasso import group_size_sqrt_from_shapes
@@ -498,12 +497,13 @@ def _build_chunk_fn(trainer, unit_map, base_shapes, flat: UnitFlat, lam,
     # Quarantine health state (and the quar trail) is a fleet-wide order
     # statistic computed on gathered norms — replicated [W] rows.
     kt = P(None, fleet_axis)
-    return jax.jit(shard_map_compat(
+    return jax.jit(jax.shard_map(
         chunk, mesh=mesh,
         in_specs=(fleet, fleet, fleet, rep, fleet, rep, fleet, fleet, fleet,
                   per_round_specs, fleet),
         out_specs=(fleet, fleet, fleet, rep, fleet, rep, P(None, fleet_axis),
                    rep, kt, kt, rep),
+        check_vma=False,
     ))
 
 
@@ -688,6 +688,13 @@ def run_sync_fused(sim, env):
         {"strikes": jnp.zeros(W, jnp.int32), "quar": jnp.zeros(W, jnp.int32)}
         if quar_cfg is not None else {}
     )
+    if mesh is not None:
+        # the chunk returns the global and health carries replicated on the
+        # mesh; start them there too, or the second chunk call sees new
+        # input shardings and jit compiles the chunk program again
+        global_dev, health_dev = jax.device_put(
+            (global_dev, health_dev), NamedSharding(mesh, P())
+        )
 
     t = 0
     while t < sim.rounds:
@@ -1056,6 +1063,7 @@ def run_sync_fused(sim, env):
             **fault_ledger(plan_all.events),
             "quarantined_commits": quarantined_commits,
         },
+        stack_devices=len(next(iter(state.params.values())).sharding.device_set),
     )
 
 

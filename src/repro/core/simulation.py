@@ -253,10 +253,10 @@ class SimConfig:
     # device compute path of the masked engine's programs: "dense" executes
     # base-shape convs under 0/1 masks (full FLOPs), "block_skip" dispatches
     # convs + head through kernels.pruned_matmul so device FLOPs track
-    # retention (requires engine="masked"; interpret-mode fallback off-TPU)
+    # retention (requires engine="masked"; Pallas interpreter on CPU)
     compute: str = "dense"
-    # pruned_matmul tile sizes (block_m, block_n, block_k); 128-aligned on
-    # TPU, shrink for fine-grained CPU/interpret runs and small models
+    # pruned_matmul tile sizes (block_m, block_n, block_k); multiples of 128
+    # on TPU (enforced), smaller only for CPU/interpret runs and small models
     compute_blocks: Tuple[int, int, int] = (128, 128, 128)
     # client sampling / dropout / churn (core.scenario); async methods
     # honour sampling + dropout (timed-out commits) and reject churn
@@ -324,6 +324,9 @@ class SimResult:
     # (step pads to the per-phase max, pow2 bucket-row pads) is excluded —
     # identical across compute paths, so ratios between them are unaffected.
     compute: str = "dense"
+    # True when the block-skip kernel ran in the Pallas interpreter (CPU),
+    # False when it was compiled by Mosaic (TPU) or never ran
+    compute_interpret: bool = False
     flops_executed: float = 0.0
     flops_ideal: float = 0.0
     blocks_executed: float = 0.0
@@ -340,9 +343,10 @@ class SimResult:
     compile_walltime_s: float = 0.0
     # fused engine: number of lax.scan chunk programs launched
     fused_chunks: int = 0
-    # mesh the run executed on (SimConfig.mesh): total devices, fleet-axis
-    # extent, and the [W, ...] stack PartitionSpec — 1/1/None on
-    # single-device runs, so every BENCH row records its mesh
+    # mesh the run executed on (SimConfig.mesh): devices the resident
+    # [W, ...] stacks were found on at the end of a fused run (read off the
+    # arrays' shardings, not the config), fleet-axis extent, and the stack
+    # PartitionSpec — 1/1/None on single-device runs
     n_devices: int = 1
     fleet_axis_size: int = 1
     shard_spec: Optional[str] = None
@@ -1738,7 +1742,7 @@ def _finalize(sim, env, acc_time, het_traj, sim_traj, upd_times, retentions,
               global_params=None, host_roundtrips=0,
               scenario_rounds=None, flops_per_image_final=0.0,
               blocks_per_image_final=0.0, prune_events=None,
-              fused_chunks=0, fault_ledger=None) -> SimResult:
+              fused_chunks=0, fault_ledger=None, stack_devices=1) -> SimResult:
     accs = np.array([a for _, a in acc_time])
     times = np.array([t for t, _ in acc_time])
     best = int(np.argmax(accs))
@@ -1746,11 +1750,10 @@ def _finalize(sim, env, acc_time, het_traj, sim_traj, upd_times, retentions,
     flops = [cnn_flops(p, sim.cnn) for p in worker_params]
     full_size = sum(v.size for v in env.base_params.values())
     if sim.mesh is not None:
-        n_devices = int(np.prod(list(sim.mesh.shape.values())))
         fleet_axis_size = int(sim.mesh.shape[sim.fleet_axis])
         shard_spec = f"PartitionSpec({sim.fleet_axis!r})"
     else:
-        n_devices, fleet_axis_size, shard_spec = 1, 1, None
+        fleet_axis_size, shard_spec = 1, None
     return SimResult(
         method=sim.method,
         acc_time=acc_time,
@@ -1773,7 +1776,7 @@ def _finalize(sim, env, acc_time, het_traj, sim_traj, upd_times, retentions,
         host_dispatches=env.trainer.dispatch_count,
         compile_walltime_s=env.trainer.compile_walltime_s,
         fused_chunks=fused_chunks,
-        n_devices=n_devices,
+        n_devices=stack_devices,
         fleet_axis_size=fleet_axis_size,
         shard_spec=shard_spec,
         prune_events=prune_events or [],
@@ -1781,6 +1784,9 @@ def _finalize(sim, env, acc_time, het_traj, sim_traj, upd_times, retentions,
         **(fault_ledger or {}),
         bucket_sizes=sorted(env.fleet.buckets_used),
         compute=sim.compute,
+        compute_interpret=(
+            sim.compute == "block_skip" and env.trainer.compute_interpret
+        ),
         flops_executed=env.flops_executed,
         flops_ideal=env.flops_ideal,
         blocks_executed=env.blocks_executed,

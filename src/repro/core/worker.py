@@ -156,7 +156,8 @@ class LocalTrainer:
     ``bn_g`` mask rows), so a pruned worker's device FLOPs track its
     retention.  Only the masked/resident paths honour it — the unmasked
     engines run physically reconfigured models, which are already sized.
-    ``interpret=None`` auto-selects per backend (Python interpreter off-TPU).
+    ``interpret=None`` auto-selects per backend (interpreter on CPU, Mosaic
+    on TPU).
     """
 
     def __init__(

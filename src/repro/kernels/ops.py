@@ -1,9 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode —
-the kernel body runs as Python/jnp over the same BlockSpec tiling, which is
-what the allclose tests validate.  On a real TPU backend they compile to
-Mosaic.  ``auto_interpret()`` picks per-backend.
+On the CPU backend the kernels execute in ``interpret=True`` mode — the
+kernel body runs as Python/jnp over the same BlockSpec tiling, which is what
+the allclose tests validate.  On a TPU backend they compile to Mosaic.
+``auto_interpret()`` picks per backend and refuses any other one, so a run
+never falls back to the interpreter by accident.
 """
 from __future__ import annotations
 
@@ -20,7 +21,13 @@ __all__ = ["auto_interpret", "pruned_matmul", "flash_attention", "rg_lru_scan"]
 
 
 def auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"the Pallas kernels compile for TPU and interpret on CPU; "
+            f"backend {backend!r} is neither — pass interpret= explicitly"
+        )
+    return backend == "cpu"
 
 
 def pruned_matmul(x, w, in_mask, out_mask, row_mask=None, **kw):

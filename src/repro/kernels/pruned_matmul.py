@@ -81,7 +81,7 @@ def _kernel(
         (m_keep_ref[mi] > 0) & (n_keep_ref[ni] > 0) & (k_keep_ref[ki] > 0)
     )
     def _compute():
-        xm = x_ref[...].astype(jnp.float32) * in_mask_ref[...].astype(jnp.float32)[None, :]
+        xm = x_ref[...].astype(jnp.float32) * in_mask_ref[...].astype(jnp.float32)
         acc_ref[...] += jax.lax.dot_general(
             xm,
             w_ref[...].astype(jnp.float32),
@@ -93,8 +93,8 @@ def _kernel(
     def _finish():
         o_ref[...] = (
             acc_ref[...]
-            * out_mask_ref[...].astype(jnp.float32)[None, :]
-            * row_mask_ref[...].astype(jnp.float32)[:, None]
+            * out_mask_ref[...].astype(jnp.float32)
+            * row_mask_ref[...].astype(jnp.float32)
         ).astype(o_ref.dtype)
 
 
@@ -103,6 +103,21 @@ def _pad_to(a: jnp.ndarray, mults) -> jnp.ndarray:
     if any(p for _, p in pads):
         a = jnp.pad(a, pads)
     return a
+
+
+def _check_blocks(block_m: int, block_n: int, block_k: int, *, grad: bool) -> None:
+    """Reject tiles the compiled (Mosaic) kernel cannot lay out.  K and N
+    blocks are lane dims: multiples of 128.  The custom VJP re-orients the
+    kernel so that dW contracts over M, which makes block_m a lane dim too
+    once gradients flow.  Interpret mode takes any size."""
+    lanes = (block_m, block_n, block_k) if grad else (block_n, block_k)
+    if any(b % 128 for b in lanes) or block_m % 8:
+        need = "all three" if grad else "block_n and block_k (block_m: of 8)"
+        raise ValueError(
+            f"compute_blocks=({block_m}, {block_n}, {block_k}): the compiled "
+            f"TPU kernel needs multiples of 128 for {need}; smaller tiles run "
+            "in interpret mode only"
+        )
 
 
 def _keep_flags(mask: jnp.ndarray, block: int) -> jnp.ndarray:
@@ -141,6 +156,9 @@ def _call(
     m_keep = _keep_flags(row_mask, block_m)
     k_keep = _keep_flags(in_mask, block_k)
     n_keep = _keep_flags(out_mask, block_n)
+    # masks enter the kernel 2-D — [1, K] / [1, N] rows and an [M, 1]
+    # column — so every block is a (sublane, lane) tile Mosaic can lay out
+    # and the body broadcasts them without a reshape
 
     grid = (Mp // block_m, Np // block_n, Kp // block_k)
     out = pl.pallas_call(
@@ -151,16 +169,17 @@ def _call(
             in_specs=[
                 pl.BlockSpec((block_m, block_k), lambda i, j, k, *_: (i, k)),
                 pl.BlockSpec((block_k, block_n), lambda i, j, k, *_: (k, j)),
-                pl.BlockSpec((block_k,), lambda i, j, k, *_: (k,)),
-                pl.BlockSpec((block_n,), lambda i, j, k, *_: (j,)),
-                pl.BlockSpec((block_m,), lambda i, j, k, *_: (i,)),
+                pl.BlockSpec((1, block_k), lambda i, j, k, *_: (0, k)),
+                pl.BlockSpec((1, block_n), lambda i, j, k, *_: (0, j)),
+                pl.BlockSpec((block_m, 1), lambda i, j, k, *_: (i, 0)),
             ],
             out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k, *_: (i, j)),
             scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),
         interpret=interpret,
-    )(m_keep, k_keep, n_keep, x, w, in_mask, out_mask, row_mask)
+    )(m_keep, k_keep, n_keep, x, w,
+      in_mask[None, :], out_mask[None, :], row_mask[:, None])
     return out[:M, :N]
 
 
@@ -177,6 +196,8 @@ def pruned_matmul_kernel_call(
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Forward-only kernel call (no autodiff rule); see ``pruned_matmul``."""
+    if not interpret:
+        _check_blocks(block_m, block_n, block_k, grad=False)
     if row_mask is None:
         row_mask = jnp.ones((x.shape[0],), jnp.float32)
     return _call(x, w, in_mask, out_mask, row_mask, block_m, block_n, block_k, interpret)
@@ -240,6 +261,8 @@ def pruned_matmul(
     (padded to block multiples internally); vmap-able over a leading batch
     axis with per-row masks — the resident fleet's one-program dispatch.
     """
+    if not interpret:
+        _check_blocks(block_m, block_n, block_k, grad=True)
     if row_mask is None:
         row_mask = jnp.ones((x.shape[0],), jnp.float32)
     return _pm_ad(x, w, in_mask, out_mask, row_mask, block_m, block_n, block_k, interpret)

@@ -257,7 +257,7 @@ def cnn_apply(
     numerically the same function as the dense path on masked params (pruned
     units are exact zeros either way), but fully-pruned mask blocks execute
     zero MXU passes.  ``blocks``/``interpret`` forward to the kernel
-    (``interpret=None`` auto-selects: interpreter everywhere but TPU).
+    (``interpret=None`` auto-selects: interpreter on CPU, Mosaic on TPU).
     """
     if compute not in ("dense", "block_skip"):
         raise ValueError(f"unknown compute path {compute!r}")

@@ -1,7 +1,7 @@
 """Mask-aware block-skip compute path: kernel VJP, model lowering, fleet
 equivalence, and the FLOPs-track-retention ledger.
 
-Everything runs ``interpret=True`` on CPU (the kernels' off-TPU fallback), so
+Everything runs ``interpret=True`` on CPU (the kernels' CPU mode), so
 the whole file is CI-runnable; on a TPU backend the same code compiles to
 Mosaic.  The contracts pinned here:
 
@@ -203,6 +203,7 @@ def test_engine_equivalence_dense_vs_block_skip(sims):
         np.testing.assert_allclose(bs.global_params[k], dense.global_params[k],
                                    atol=1e-4, err_msg=k)
     assert bs.compute == "block_skip" and dense.compute == "dense"
+    assert bs.compute_interpret and not dense.compute_interpret   # CPU backend
     assert bs.recompiles == dense.recompiles  # block-skip adds no shapes
 
 

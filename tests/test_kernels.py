@@ -130,3 +130,34 @@ def test_rg_lru_matches_model_recurrence():
     x = jax.random.normal(jax.random.PRNGKey(4), (2, 128, 64)) * 0.3
     out_model, state = rglru_fwd(p, spec, x)
     assert np.isfinite(np.asarray(out_model)).all()
+
+
+@pytest.mark.parametrize(
+    "blocks,grad",
+    [((128, 8, 8), True), ((128, 128, 64), False), ((64, 128, 128), True)],
+)
+def test_compiled_kernel_rejects_unaligned_blocks(blocks, grad):
+    """Tiles Mosaic cannot lay out fail fast, naming ``compute_blocks``,
+    instead of failing inside the TPU compiler; interpret mode takes them."""
+    from repro.kernels.pruned_matmul import pruned_matmul, pruned_matmul_kernel_call
+
+    x, w = jnp.ones((16, 16)), jnp.ones((16, 16))
+    m = jnp.ones((16,))
+    bm, bn, bk = blocks
+    call = pruned_matmul if grad else pruned_matmul_kernel_call
+    with pytest.raises(ValueError, match="compute_blocks"):
+        call(x, w, m, m, block_m=bm, block_n=bn, block_k=bk, interpret=False)
+    y = call(x, w, m, m, block_m=bm, block_n=bn, block_k=bk, interpret=True)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(x @ w), rtol=1e-6)
+
+
+@pytest.mark.parametrize("backend,expected", [("cpu", True), ("tpu", False), ("gpu", None)])
+def test_auto_interpret_only_on_cpu(monkeypatch, backend, expected):
+    """Interpret on CPU, compile on TPU, refuse anything else: no backend
+    falls back to the interpreter in silence."""
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    if expected is None:
+        with pytest.raises(RuntimeError, match="interpret"):
+            ops.auto_interpret()
+    else:
+        assert ops.auto_interpret() is expected

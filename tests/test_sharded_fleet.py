@@ -170,6 +170,23 @@ def test_simresult_records_the_mesh(eight_devices):
     assert shd.shard_spec == "PartitionSpec('fleet')"
 
 
+def test_sharded_chunk_program_compiles_once(eight_devices, caplog):
+    """Every chunk call after the first reuses the first compile: the carries
+    a chunk returns replicated (the global model) start out replicated too,
+    so no later call brings new input shardings that recompile in silence
+    behind ``recompiles == 1``."""
+    import logging
+
+    import jax
+
+    with jax.log_compiles(True), caplog.at_level(logging.WARNING):
+        shd = _sim("fused", mesh=_mesh(4), eval_every=6)
+    assert shd.fused_chunks > 1 and shd.recompiles == 1
+    chunk_compiles = [r for r in caplog.records
+                      if "compilation of jit(chunk)" in r.getMessage()]
+    assert len(chunk_compiles) == 1
+
+
 # ---------------------------------------------------------------------------
 # global -> (shard, local) index algebra
 # ---------------------------------------------------------------------------
@@ -234,7 +251,6 @@ def test_two_tier_aggregation_matches_single_device(eight_devices):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.sharding.compat import shard_map_compat
     from repro.sharding.specs import fleet_sharding
 
     mesh = _mesh(4)
@@ -258,13 +274,15 @@ def test_two_tier_aggregation_matches_single_device(eight_devices):
     dstacks = {k: jax.device_put(v, sh) for k, v in stacks.items()}
     dmasks = {k: jax.device_put(v, sh) for k, v in masks.items()}
 
-    two_w = shard_map_compat(
+    two_w = jax.shard_map(
         lambda s, w: aggregate_by_worker_stacked_jnp(s, w, axis="fleet"),
         mesh=mesh, in_specs=(P("fleet"), P("fleet")), out_specs=P(),
+        check_vma=False,
     )(dstacks, jax.device_put(weights, sh))
-    two_u = shard_map_compat(
+    two_u = jax.shard_map(
         lambda s, m, sub: aggregate_by_unit_stacked_jnp(s, m, sub, axis="fleet"),
         mesh=mesh, in_specs=(P("fleet"), P("fleet"), P("fleet")), out_specs=P(),
+        check_vma=False,
     )(dstacks, dmasks, jax.device_put(submitters, sh))
 
     for k in stacks:
