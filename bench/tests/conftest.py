@@ -1,0 +1,7 @@
+"""The benchmark's tests import the simulator from ``src/`` as the harness does."""
+import sys
+from pathlib import Path
+
+_SRC = str(Path(__file__).resolve().parents[2] / "src")
+if _SRC not in sys.path:
+    sys.path.insert(0, _SRC)
