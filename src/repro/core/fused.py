@@ -93,7 +93,6 @@ a scan (fused rounds compute all W rows with validity masks), and the
 """
 from __future__ import annotations
 
-import time as _time
 from typing import Dict, List, Optional
 
 import jax
@@ -143,6 +142,7 @@ from .masks import (
 )
 from .pruned_rate import WorkerHistory, learn_pruned_rates
 from .scenario import ScenarioEngine, ScenarioPlan
+from .spans import span
 from .timing import heterogeneity_from_times
 from .worker import make_batch_plan, plan_steps, stack_batch_plans
 
@@ -318,9 +318,14 @@ def _build_chunk_fn(trainer, unit_map, base_shapes, flat: UnitFlat, lam,
                  or robust is not None or channel)
     quar_cfg = robust.quarantine if robust is not None else None
 
+    # Device scopes (``jax.named_scope``) name each phase of a round in the
+    # op names of the compiled program, so a profile's device ops sort into
+    # broadcast / train (``worker.make_resident_train``) / importance /
+    # prune / dgc / aggregate; they change HLO metadata only, not fusion.
     def chunk(params, momentum, presence, global_p, dgc_res, health, xs, ys,
               sizes, per_round, orders):
-        masks = masks_from_presence(presence, flat, unit_map, base_shapes)
+        with jax.named_scope("broadcast"):
+            masks = masks_from_presence(presence, flat, unit_map, base_shapes)
 
         def body(carry, inp):
             params, masks, presence, global_p, momentum, dgc_res, health = carry
@@ -329,25 +334,26 @@ def _build_chunk_fn(trainer, unit_map, base_shapes, flat: UnitFlat, lam,
             # velocity/DGC residuals (they were accumulated against
             # pre-crash parameters).  All-zero on fault-free rounds, so the
             # compiled program is shared and the fault-free math unchanged.
-            if resident_momentum or use_dgc:
-                keep = 1.0 - inp["recov"]
-                if resident_momentum:
-                    momentum = {
-                        k: v * keep.reshape((-1,) + (1,) * (v.ndim - 1))
-                        for k, v in momentum.items()
-                    }
-                if use_dgc:
-                    dgc_res = {
-                        k: v * keep.reshape((-1,) + (1,) * (v.ndim - 1))
-                        for k, v in dgc_res.items()
-                    }
-            # broadcast-back: masked scatter of the global into every row
-            params = {k: global_p[k][None] * masks[k] for k in params}
-            gl = gl_factors_from_counts(
-                counts_of(presence), unit_map, base_shapes
-            )
-            m0 = (momentum if resident_momentum
-                  else jax.tree.map(jnp.zeros_like, params))
+            with jax.named_scope("broadcast"):
+                if resident_momentum or use_dgc:
+                    keep = 1.0 - inp["recov"]
+                    if resident_momentum:
+                        momentum = {
+                            k: v * keep.reshape((-1,) + (1,) * (v.ndim - 1))
+                            for k, v in momentum.items()
+                        }
+                    if use_dgc:
+                        dgc_res = {
+                            k: v * keep.reshape((-1,) + (1,) * (v.ndim - 1))
+                            for k, v in dgc_res.items()
+                        }
+                # broadcast-back: masked scatter of the global into every row
+                params = {k: global_p[k][None] * masks[k] for k in params}
+                gl = gl_factors_from_counts(
+                    counts_of(presence), unit_map, base_shapes
+                )
+                m0 = (momentum if resident_momentum
+                      else jax.tree.map(jnp.zeros_like, params))
             params, m_out, _ = vm_train(
                 params, m0, xs, ys, inp["plan_a"], inp["valid_a"], masks, gl
             )
@@ -355,32 +361,35 @@ def _build_chunk_fn(trainer, unit_map, base_shapes, flat: UnitFlat, lam,
 
             def prune_branch(op):
                 params, masks, presence, momentum = op
-                if importance in STATIC_METHODS:
-                    ow = orders
-                else:
-                    scores = device_scores(
-                        params, masks, presence, xs, ys, sizes
+                with jax.named_scope("importance"):
+                    if importance in STATIC_METHODS:
+                        ow = orders
+                    else:
+                        scores = device_scores(
+                            params, masks, presence, xs, ys, sizes
+                        )
+                        ow = jax.vmap(
+                            lambda s: jnp.lexsort((tiebreak_dev, s)).astype(jnp.int32)
+                        )(scores)
+                with jax.named_scope("prune"):
+                    pres2 = prune_presence_rows(presence, ow, inp["budgets"], flat)
+                    masks2 = masks_from_presence(pres2, flat, unit_map, base_shapes)
+                    params2 = {k: params[k] * masks2[k] for k in params}
+                    mom2 = (
+                        {k: momentum[k] * masks2[k] for k in momentum}
+                        if resident_momentum else momentum
                     )
-                    ow = jax.vmap(
-                        lambda s: jnp.lexsort((tiebreak_dev, s)).astype(jnp.int32)
-                    )(scores)
-                pres2 = prune_presence_rows(presence, ow, inp["budgets"], flat)
-                masks2 = masks_from_presence(pres2, flat, unit_map, base_shapes)
-                params2 = {k: params[k] * masks2[k] for k in params}
-                mom2 = (
-                    {k: momentum[k] * masks2[k] for k in momentum}
-                    if resident_momentum else momentum
-                )
                 if has_phase_b:
-                    gl2 = gl_factors_from_counts(
-                        counts_of(pres2), unit_map, base_shapes
-                    )
-                    m0b = (mom2 if resident_momentum
-                           else jax.tree.map(jnp.zeros_like, params2))
-                    params2, m_b, _ = vm_train(
-                        params2, m0b, xs, ys,
-                        inp["plan_b"], inp["valid_b"], masks2, gl2,
-                    )
+                    with jax.named_scope("train"):
+                        gl2 = gl_factors_from_counts(
+                            counts_of(pres2), unit_map, base_shapes
+                        )
+                        m0b = (mom2 if resident_momentum
+                               else jax.tree.map(jnp.zeros_like, params2))
+                        params2, m_b, _ = vm_train(
+                            params2, m0b, xs, ys,
+                            inp["plan_b"], inp["valid_b"], masks2, gl2,
+                        )
                     mom2 = m_b if resident_momentum else mom2
                 return params2, masks2, pres2, mom2
 
@@ -392,67 +401,69 @@ def _build_chunk_fn(trainer, unit_map, base_shapes, flat: UnitFlat, lam,
             # submission boundary: DGC top-|.| delta compression on device.
             # Deltas are vs the masked broadcast-back; submitters-gated, so
             # dead padding rounds (submitters all 0) touch no residual.
-            if use_dgc:
-                deltas = {
-                    k: params[k] - global_p[k][None] * masks[k] for k in params
-                }
-                committed, dgc_res, kept_w, total_w = dgc_compress_jnp(
-                    deltas, dgc_res, dgc_sparsity, masks, inp["submitters"]
-                )
-                agg_in = {
-                    k: global_p[k][None] * masks[k] + committed[k]
-                    for k in params
-                }
-            else:
-                agg_in = params
-                kept_w = total_w = None
+            with jax.named_scope("dgc"):
+                if use_dgc:
+                    deltas = {
+                        k: params[k] - global_p[k][None] * masks[k] for k in params
+                    }
+                    committed, dgc_res, kept_w, total_w = dgc_compress_jnp(
+                        deltas, dgc_res, dgc_sparsity, masks, inp["submitters"]
+                    )
+                    agg_in = {
+                        k: global_p[k][None] * masks[k] + committed[k]
+                        for k in params
+                    }
+                else:
+                    agg_in = params
+                    kept_w = total_w = None
 
-            agg_axis = fleet_axis if mesh is not None else None
-            quar_row = None
-            if by_unit:
-                g_new = aggregate_by_unit_stacked_jnp(
-                    agg_in, masks, inp["submitters"], axis=agg_axis
-                )
-            elif robust_on:
-                # noise keys derive from the ROUND NUMBER in-scan via the
-                # same fold_in chain the masked loop runs eagerly — threefry
-                # is deterministic, so the streams are bit-identical.
-                byz_key = (
-                    noise_key(noise_seed + 51721, inp["rnd"])
-                    if byz is not None else None
-                )
-                cor_key = (
-                    noise_key(noise_seed + 51722, inp["rnd"])
-                    if corrupt_std is not None else None
-                )
-                g_new, st2, qu2, quar_row = robust_submission_step_jnp(
-                    agg_in, masks, global_p, inp["mult"], inp["weights"],
-                    inp["byz"] if byz is not None else None,
-                    inp["corrupt"] if corrupt_std is not None else None,
-                    byz_key, cor_key,
-                    health.get("strikes"), health.get("quar"),
-                    byz_mode=byz.mode if byz is not None else "sign_flip",
-                    byz_scale=byz.scale if byz is not None else -10.0,
-                    byz_noise_std=byz.noise_std if byz is not None else 1.0,
-                    corrupt_std=corrupt_std if corrupt_std is not None else 10.0,
-                    clip=robust.clip if robust is not None else None,
-                    trim=robust.trim if robust is not None else 0.0,
-                    quarantine=quar_cfg,
-                    gate=inp["real"], axis=agg_axis, full_rows=fleet_w,
-                )
-                if quar_cfg is not None:
-                    health = {"strikes": st2, "quar": qu2}
-            else:
-                g_new = aggregate_by_worker_stacked_jnp(
-                    agg_in, inp["weights"], axis=agg_axis
-                )
-            # dead padding rounds (real=False) keep the global untouched, so
-            # every chunk shares ONE [K]-shaped compiled program
-            global_p = {
-                k: jnp.where(inp["real"], g_new[k].astype(jnp.float32),
-                             global_p[k])
-                for k in global_p
-            }
+            with jax.named_scope("aggregate"):
+                agg_axis = fleet_axis if mesh is not None else None
+                quar_row = None
+                if by_unit:
+                    g_new = aggregate_by_unit_stacked_jnp(
+                        agg_in, masks, inp["submitters"], axis=agg_axis
+                    )
+                elif robust_on:
+                    # noise keys derive from the ROUND NUMBER in-scan via the
+                    # same fold_in chain the masked loop runs eagerly — threefry
+                    # is deterministic, so the streams are bit-identical.
+                    byz_key = (
+                        noise_key(noise_seed + 51721, inp["rnd"])
+                        if byz is not None else None
+                    )
+                    cor_key = (
+                        noise_key(noise_seed + 51722, inp["rnd"])
+                        if corrupt_std is not None else None
+                    )
+                    g_new, st2, qu2, quar_row = robust_submission_step_jnp(
+                        agg_in, masks, global_p, inp["mult"], inp["weights"],
+                        inp["byz"] if byz is not None else None,
+                        inp["corrupt"] if corrupt_std is not None else None,
+                        byz_key, cor_key,
+                        health.get("strikes"), health.get("quar"),
+                        byz_mode=byz.mode if byz is not None else "sign_flip",
+                        byz_scale=byz.scale if byz is not None else -10.0,
+                        byz_noise_std=byz.noise_std if byz is not None else 1.0,
+                        corrupt_std=corrupt_std if corrupt_std is not None else 10.0,
+                        clip=robust.clip if robust is not None else None,
+                        trim=robust.trim if robust is not None else 0.0,
+                        quarantine=quar_cfg,
+                        gate=inp["real"], axis=agg_axis, full_rows=fleet_w,
+                    )
+                    if quar_cfg is not None:
+                        health = {"strikes": st2, "quar": qu2}
+                else:
+                    g_new = aggregate_by_worker_stacked_jnp(
+                        agg_in, inp["weights"], axis=agg_axis
+                    )
+                # dead padding rounds (real=False) keep the global untouched, so
+                # every chunk shares ONE [K]-shaped compiled program
+                global_p = {
+                    k: jnp.where(inp["real"], g_new[k].astype(jnp.float32),
+                                 global_p[k])
+                    for k in global_p
+                }
             return (
                 params, masks, presence, global_p, momentum, dgc_res, health
             ), (presence, global_p, kept_w, total_w, quar_row)
@@ -522,486 +533,495 @@ def run_sync_fused(sim, env):
         _skip_round_time,
     )
 
-    validate_fused_config(sim)
-    W = sim.num_workers
-    use_dgc = sim.dgc_sparsity > 0.0
-    adapt = sim.method == "adaptcl"
-    sparse = sim.method in ("fedavg_s", "adaptcl")
-    lam = sim.lam if sparse else 0.0
-    trainer = env.trainer
-    unit_map = env.unit_map
-    base_shapes = env.base_shapes
-    flat = flatten_unit_space(env.space)
-    U = flat.num_units
-    mesh = getattr(sim, "mesh", None)
-    state_sharding = (
-        fleet_sharding(mesh, sim.fleet_axis) if mesh is not None else None
-    )
-
-    # robust-aggregation statics (byzantine transform / lossy channel /
-    # clip-trim-quarantine).  All None => the chunk program and every host
-    # array below are byte-for-byte the pre-feature ones.
-    faults_cfg = (
-        sim.scenario.faults
-        if sim.scenario is not None and sim.scenario.faults is not None
-        else None
-    )
-    byz_cfg = faults_cfg.byzantine if faults_cfg is not None else None
-    ch_cfg = faults_cfg.channel if faults_cfg is not None else None
-    corrupt_on = ch_cfg is not None and ch_cfg.corrupt > 0.0
-    rb_cfg = (
-        sim.robust
-        if sim.robust is not None and sim.robust.any_active else None
-    )
-    quar_cfg = rb_cfg.quarantine if rb_cfg is not None else None
-    robust_on = (
-        byz_cfg is not None or ch_cfg is not None or rb_cfg is not None
-    )
-    quarantined_commits = 0
-
-    scen = ScenarioEngine(sim.scenario, W) if sim.scenario is not None else None
-    if scen is not None:
-        plan_all = scen.draw_all(
-            sim.rounds,
-            shard_sizes=[len(s) for s in env.shards],
-            train_len=len(env.task.y_train),
+    # set-up, up to the first chunk's plan (the env's own is in
+    # run_simulation's set-up span)
+    with span("setup"):
+        validate_fused_config(sim)
+        W = sim.num_workers
+        use_dgc = sim.dgc_sparsity > 0.0
+        adapt = sim.method == "adaptcl"
+        sparse = sim.method in ("fedavg_s", "adaptcl")
+        lam = sim.lam if sparse else 0.0
+        trainer = env.trainer
+        unit_map = env.unit_map
+        base_shapes = env.base_shapes
+        flat = flatten_unit_space(env.space)
+        U = flat.num_units
+        mesh = getattr(sim, "mesh", None)
+        state_sharding = (
+            fleet_sharding(mesh, sim.fleet_axis) if mesh is not None else None
         )
-    else:
-        plan_all = ScenarioPlan.full(sim.rounds, W)
 
-    shard_x, shard_y = zip(*(env.shard_xy(w) for w in range(W)))
-    state = env.fleet.init_state(
-        env.base_params, list(shard_x), list(shard_y),
-        sharding=state_sharding,
-    )
-    if sim.resident_momentum:
-        env.fleet.init_momentum(state)
+        # robust-aggregation statics (byzantine transform / lossy channel /
+        # clip-trim-quarantine).  All None => the chunk program and every host
+        # array below are byte-for-byte the pre-feature ones.
+        faults_cfg = (
+            sim.scenario.faults
+            if sim.scenario is not None and sim.scenario.faults is not None
+            else None
+        )
+        byz_cfg = faults_cfg.byzantine if faults_cfg is not None else None
+        ch_cfg = faults_cfg.channel if faults_cfg is not None else None
+        corrupt_on = ch_cfg is not None and ch_cfg.corrupt > 0.0
+        rb_cfg = (
+            sim.robust
+            if sim.robust is not None and sim.robust.any_active else None
+        )
+        quar_cfg = rb_cfg.quarantine if rb_cfg is not None else None
+        robust_on = (
+            byz_cfg is not None or ch_cfg is not None or rb_cfg is not None
+        )
+        quarantined_commits = 0
 
-    batch = sim.batch_size
-    pad_a = max(
-        plan_steps(len(env.shards[w]), batch, sim.local_epochs)
-        for w in range(W)
-    )
-    pad_b = max(
-        plan_steps(len(env.shards[w]), batch, (1 - sim.beta) * sim.local_epochs)
-        for w in range(W)
-    )
-    K_pad = sim.round_fusion if sim.round_fusion > 0 else (
-        sim.prune_interval if adapt else 8
-    )
-    K_pad = max(1, min(K_pad, sim.rounds))
-
-    global_params = {k: np.asarray(v) for k, v in env.base_params.items()}
-    global_dev = {k: jnp.asarray(v) for k, v in global_params.items()}
-    sizes_dev = jnp.asarray(np.asarray(state.shard_sizes, np.int32))
-    # DGC residual accumulators live on device, carried across chunks like
-    # the momentum stacks ({} when DGC is off: an empty pytree)
-    dgc_res_dev = (
-        {
-            k: jnp.zeros((W,) + tuple(s), jnp.float32)
-            for k, s in env.base_shapes.items()
-        }
-        if use_dgc else {}
-    )
-    if use_dgc and state_sharding is not None:
-        dgc_res_dev = jax.device_put(dgc_res_dev, state_sharding)
-
-    indices = [full_index(env.space) for _ in range(W)]
-    histories = [WorkerHistory() for _ in range(W)]
-    pending_rates = [0.0] * W
-    cig_scores = None
-    interval_phis: List[List[float]] = [[] for _ in range(W)]
-    prune_round_count = 0
-    prune_events = []
-    fused_chunks = 0
-
-    clock = 0.0
-    comm_bytes = 0.0
-    server_overhead = 0.0
-    acc_time, het_traj, sim_traj, upd_times = [], [], [], []
-    scen_rows = []
-
-    # channel-model cache: payload bytes + FLOPs depend on the index only
-    # through per-layer retained COUNTS, so the per-(round, worker) phi math
-    # collapses to a dict lookup + the exact float ops of _phi_from_shapes —
-    # bit-identical values, O(distinct retentions) instead of O(R x W) host
-    # shape walks
-    _count_cache: Dict[tuple, tuple] = {}
-
-    def _bytes_flops(idx) -> tuple:
-        key = tuple(len(idx[name]) for name in flat.names)
-        ent = _count_cache.get(key)
-        if ent is None:
-            shapes = subparam_shapes(idx, unit_map, base_shapes)
-            ent = (
-                sum(int(np.prod(s)) * 4 for s in shapes.values()),
-                cnn_flops_from_shapes(shapes, sim.cnn),
+        scen = ScenarioEngine(sim.scenario, W) if sim.scenario is not None else None
+        if scen is not None:
+            plan_all = scen.draw_all(
+                sim.rounds,
+                shard_sizes=[len(s) for s in env.shards],
+                train_len=len(env.task.y_train),
             )
-            _count_cache[key] = ent
-        return ent
+        else:
+            plan_all = ScenarioPlan.full(sim.rounds, W)
 
-    acc_time.append((0.0, _env_accuracy(env, global_params)))
-    rt_base = roundtrip_total()
-
-    sig_shapes = tuple(
-        sorted((k, tuple(v.shape)) for k, v in state.params.items())
-    )
-    mesh_sig = (
-        (sim.fleet_axis, int(mesh.shape[sim.fleet_axis]),
-         tuple(int(d.id) for d in mesh.devices.flat))
-        if mesh is not None else None
-    )
-    rb_sig = (
-        ((byz_cfg.mode, float(byz_cfg.scale), float(byz_cfg.noise_std))
-         if byz_cfg is not None else None),
-        (float(ch_cfg.corrupt_std) if corrupt_on else None,
-         ch_cfg is not None),
-        ((None if rb_cfg.clip is None else float(rb_cfg.clip),
-          float(rb_cfg.trim),
-          ((float(quar_cfg.threshold), int(quar_cfg.strikes),
-            int(quar_cfg.probation)) if quar_cfg is not None else None))
-         if rb_cfg is not None else None),
-        int(sim.seed),
-    )
-    sig = (
-        sig_shapes,
-        ("fused", K_pad, pad_a, pad_b, tuple(state.xs.shape), batch,
-         sim.aggregation, sim.importance, bool(sim.resident_momentum),
-         float(sim.dgc_sparsity), mesh_sig, rb_sig),
-        float(lam),
-    )
-    build = lambda: _build_chunk_fn(
-        trainer, unit_map, base_shapes, flat, lam,
-        by_unit=sim.aggregation == "by_unit",
-        importance=sim.importance,
-        resident_momentum=bool(sim.resident_momentum),
-        has_phase_b=pad_b > 0,
-        dgc_sparsity=float(sim.dgc_sparsity),
-        mesh=mesh, fleet_axis=sim.fleet_axis,
-        robust=rb_cfg, byz=byz_cfg,
-        corrupt_std=float(ch_cfg.corrupt_std) if corrupt_on else None,
-        channel=ch_cfg is not None, noise_seed=int(sim.seed),
-        fleet_w=W if mesh is not None else None,
-    )
-    # quarantine health carry: full-fleet [W] rows, replicated on the mesh
-    health_dev = (
-        {"strikes": jnp.zeros(W, jnp.int32), "quar": jnp.zeros(W, jnp.int32)}
-        if quar_cfg is not None else {}
-    )
-    if mesh is not None:
-        # the chunk returns the global and health carries replicated on the
-        # mesh; start them there too, or the second chunk call sees new
-        # input shardings and jit compiles the chunk program again
-        global_dev, health_dev = jax.device_put(
-            (global_dev, health_dev), NamedSharding(mesh, P())
+        shard_x, shard_y = zip(*(env.shard_xy(w) for w in range(W)))
+        state = env.fleet.init_state(
+            env.base_params, list(shard_x), list(shard_y),
+            sharding=state_sharding,
         )
+        if sim.resident_momentum:
+            env.fleet.init_momentum(state)
+
+        batch = sim.batch_size
+        pad_a = max(
+            plan_steps(len(env.shards[w]), batch, sim.local_epochs)
+            for w in range(W)
+        )
+        pad_b = max(
+            plan_steps(len(env.shards[w]), batch, (1 - sim.beta) * sim.local_epochs)
+            for w in range(W)
+        )
+        K_pad = sim.round_fusion if sim.round_fusion > 0 else (
+            sim.prune_interval if adapt else 8
+        )
+        K_pad = max(1, min(K_pad, sim.rounds))
+
+        global_params = {k: np.asarray(v) for k, v in env.base_params.items()}
+        global_dev = {k: jnp.asarray(v) for k, v in global_params.items()}
+        sizes_dev = jnp.asarray(np.asarray(state.shard_sizes, np.int32))
+        # DGC residual accumulators live on device, carried across chunks like
+        # the momentum stacks ({} when DGC is off: an empty pytree)
+        dgc_res_dev = (
+            {
+                k: jnp.zeros((W,) + tuple(s), jnp.float32)
+                for k, s in env.base_shapes.items()
+            }
+            if use_dgc else {}
+        )
+        if use_dgc and state_sharding is not None:
+            dgc_res_dev = jax.device_put(dgc_res_dev, state_sharding)
+
+        indices = [full_index(env.space) for _ in range(W)]
+        histories = [WorkerHistory() for _ in range(W)]
+        pending_rates = [0.0] * W
+        cig_scores = None
+        interval_phis: List[List[float]] = [[] for _ in range(W)]
+        prune_round_count = 0
+        prune_events = []
+        fused_chunks = 0
+
+        clock = 0.0
+        comm_bytes = 0.0
+        acc_time, het_traj, sim_traj, upd_times = [], [], [], []
+        scen_rows = []
+
+        # channel-model cache: payload bytes + FLOPs depend on the index only
+        # through per-layer retained COUNTS, so the per-(round, worker) phi math
+        # collapses to a dict lookup + the exact float ops of _phi_from_shapes —
+        # bit-identical values, O(distinct retentions) instead of O(R x W) host
+        # shape walks
+        _count_cache: Dict[tuple, tuple] = {}
+
+        def _bytes_flops(idx) -> tuple:
+            key = tuple(len(idx[name]) for name in flat.names)
+            ent = _count_cache.get(key)
+            if ent is None:
+                shapes = subparam_shapes(idx, unit_map, base_shapes)
+                ent = (
+                    sum(int(np.prod(s)) * 4 for s in shapes.values()),
+                    cnn_flops_from_shapes(shapes, sim.cnn),
+                )
+                _count_cache[key] = ent
+            return ent
+
+        acc_time.append((0.0, _env_accuracy(env, global_params)))
+        rt_base = roundtrip_total()
+
+        sig_shapes = tuple(
+            sorted((k, tuple(v.shape)) for k, v in state.params.items())
+        )
+        mesh_sig = (
+            (sim.fleet_axis, int(mesh.shape[sim.fleet_axis]),
+             tuple(int(d.id) for d in mesh.devices.flat))
+            if mesh is not None else None
+        )
+        rb_sig = (
+            ((byz_cfg.mode, float(byz_cfg.scale), float(byz_cfg.noise_std))
+             if byz_cfg is not None else None),
+            (float(ch_cfg.corrupt_std) if corrupt_on else None,
+             ch_cfg is not None),
+            ((None if rb_cfg.clip is None else float(rb_cfg.clip),
+              float(rb_cfg.trim),
+              ((float(quar_cfg.threshold), int(quar_cfg.strikes),
+                int(quar_cfg.probation)) if quar_cfg is not None else None))
+             if rb_cfg is not None else None),
+            int(sim.seed),
+        )
+        sig = (
+            sig_shapes,
+            ("fused", K_pad, pad_a, pad_b, tuple(state.xs.shape), batch,
+             sim.aggregation, sim.importance, bool(sim.resident_momentum),
+             float(sim.dgc_sparsity), mesh_sig, rb_sig),
+            float(lam),
+        )
+        build = lambda: _build_chunk_fn(
+            trainer, unit_map, base_shapes, flat, lam,
+            by_unit=sim.aggregation == "by_unit",
+            importance=sim.importance,
+            resident_momentum=bool(sim.resident_momentum),
+            has_phase_b=pad_b > 0,
+            dgc_sparsity=float(sim.dgc_sparsity),
+            mesh=mesh, fleet_axis=sim.fleet_axis,
+            robust=rb_cfg, byz=byz_cfg,
+            corrupt_std=float(ch_cfg.corrupt_std) if corrupt_on else None,
+            channel=ch_cfg is not None, noise_seed=int(sim.seed),
+            fleet_w=W if mesh is not None else None,
+        )
+        # quarantine health carry: full-fleet [W] rows, replicated on the mesh
+        health_dev = (
+            {"strikes": jnp.zeros(W, jnp.int32), "quar": jnp.zeros(W, jnp.int32)}
+            if quar_cfg is not None else {}
+        )
+        if mesh is not None:
+            # the chunk returns the global and health carries replicated on the
+            # mesh; start them there too, or the second chunk call sees new
+            # input shardings and jit compiles the chunk program again
+            global_dev, health_dev = jax.device_put(
+                (global_dev, health_dev), NamedSharding(mesh, P())
+            )
 
     t = 0
     while t < sim.rounds:
-        # ---- chunk-start churn (host): replaced slots restart fresh ------
-        ev0 = plan_all.events[t]
-        if ev0.joined.any():
-            for w in np.flatnonzero(ev0.joined):
-                w = int(w)
-                indices[w] = full_index(env.space)
-                histories[w] = WorkerHistory()
-                pending_rates[w] = 0.0
-                interval_phis[w] = []
-                env.shards[w] = plan_all.fresh_shards[t][w]
-                env.fleet.update_shard(state, w, *env.shard_xy(w))
-                if sim.resident_momentum:
+        with span("chunk.plan"):
+            # ---- chunk-start churn (host): replaced slots restart fresh ------
+            ev0 = plan_all.events[t]
+            if ev0.joined.any():
+                for w in np.flatnonzero(ev0.joined):
+                    w = int(w)
+                    indices[w] = full_index(env.space)
+                    histories[w] = WorkerHistory()
+                    pending_rates[w] = 0.0
+                    interval_phis[w] = []
+                    env.shards[w] = plan_all.fresh_shards[t][w]
+                    env.fleet.update_shard(state, w, *env.shard_xy(w))
+                    if sim.resident_momentum:
+                        state.momentum = {
+                            k: v.at[w].set(0.0) for k, v in state.momentum.items()
+                        }
+                    if use_dgc:     # fresh slot: no carried residual
+                        dgc_res_dev = {
+                            k: v.at[w].set(0.0) for k, v in dgc_res_dev.items()
+                        }
+            # ---- FedDST mask readjustment at the chunk boundary (host).  The
+            # chunk-extent cut below guarantees a regrow round is always round
+            # t+1 of some chunk, so the shared host step runs here and the chunk
+            # simply starts from the readjusted presence rows.  Params need no
+            # touch-up (the in-scan broadcast-back re-masks them); momentum rows
+            # must drop newly-removed units explicitly when resident.
+            if _regrow_round(sim, t + 1):
+                regrown = _regrow_step(sim, env, global_params, indices, t + 1)
+                for w, idx_w in regrown:
+                    prune_events.append((
+                        t + 1, int(w),
+                        {k: tuple(map(int, v)) for k, v in idx_w.items()},
+                    ))
+                if regrown and sim.resident_momentum:
+                    pres_now = jnp.asarray(np.stack([
+                        presence_from_index(indices[w], flat) for w in range(W)
+                    ]))
+                    m_now = masks_from_presence(
+                        pres_now, flat, unit_map, base_shapes
+                    )
                     state.momentum = {
-                        k: v.at[w].set(0.0) for k, v in state.momentum.items()
+                        k: v * m_now[k] for k, v in state.momentum.items()
                     }
-                if use_dgc:     # fresh slot: no carried residual
-                    dgc_res_dev = {
-                        k: v.at[w].set(0.0) for k, v in dgc_res_dev.items()
-                    }
-        # ---- FedDST mask readjustment at the chunk boundary (host).  The
-        # chunk-extent cut below guarantees a regrow round is always round
-        # t+1 of some chunk, so the shared host step runs here and the chunk
-        # simply starts from the readjusted presence rows.  Params need no
-        # touch-up (the in-scan broadcast-back re-masks them); momentum rows
-        # must drop newly-removed units explicitly when resident.
-        if _regrow_round(sim, t + 1):
-            regrown = _regrow_step(sim, env, global_params, indices, t + 1)
-            for w, idx_w in regrown:
-                prune_events.append((
-                    t + 1, int(w),
-                    {k: tuple(map(int, v)) for k, v in idx_w.items()},
-                ))
-            if regrown and sim.resident_momentum:
-                pres_now = jnp.asarray(np.stack([
-                    presence_from_index(indices[w], flat) for w in range(W)
-                ]))
-                m_now = masks_from_presence(
-                    pres_now, flat, unit_map, base_shapes
-                )
-                state.momentum = {
-                    k: v * m_now[k] for k, v in state.momentum.items()
-                }
-        # ---- chunk extent: learning events, churn, regrow and capability
-        # drift rounds cut.  A drift-change round must be the LAST round of
-        # its chunk (the cut fires when the PREVIOUS round drifted), so the
-        # drift-triggered re-learning runs at the chunk boundary exactly
-        # where the lazy loop runs it.  Outage/skip rounds do NOT cut —
-        # they ride in-scan as dead rounds (real=False).
-        n = min(K_pad, sim.rounds - t)
-        if adapt:
-            n = min(n, sim.prune_interval - (t % sim.prune_interval))
-        for j in range(1, n):
-            if (plan_all.events[t + j].joined.any()
-                    or _regrow_round(sim, t + j + 1)
-                    or (scen is not None and scen.drift_changed(t + j))):
-                n = j
-                break
-        rounds_this = list(range(t + 1, t + n + 1))
+            # ---- chunk extent: learning events, churn, regrow and capability
+            # drift rounds cut.  A drift-change round must be the LAST round of
+            # its chunk (the cut fires when the PREVIOUS round drifted), so the
+            # drift-triggered re-learning runs at the chunk boundary exactly
+            # where the lazy loop runs it.  Outage/skip rounds do NOT cut —
+            # they ride in-scan as dead rounds (real=False).
+            n = min(K_pad, sim.rounds - t)
+            if adapt:
+                n = min(n, sim.prune_interval - (t % sim.prune_interval))
+            for j in range(1, n):
+                if (plan_all.events[t + j].joined.any()
+                        or _regrow_round(sim, t + j + 1)
+                        or (scen is not None and scen.drift_changed(t + j))):
+                    n = j
+                    break
+            rounds_this = list(range(t + 1, t + n + 1))
 
-        # ---- host pre-compute: plans / budgets / jitter, in the lazy
-        # loop's exact env.rng order (plans then jitter, per round) --------
-        plans_a = np.zeros((K_pad, W, pad_a, batch), np.int64)
-        valid_a = np.zeros((K_pad, W, pad_a), np.float32)
-        plans_b = np.zeros((K_pad, W, max(pad_b, 1), batch), np.int64)
-        valid_b = np.zeros((K_pad, W, max(pad_b, 1)), np.float32)
-        budgets = np.zeros((K_pad, W), np.int32)
-        prune_any = np.zeros((K_pad,), bool)
-        real = np.zeros((K_pad,), bool)
-        weights = np.zeros((K_pad, W), np.float32)
-        submit_m = np.zeros((K_pad, W), np.float32)
-        mult_m = np.zeros((K_pad, W), np.float32)
-        byz_m = np.zeros((K_pad, W), bool)
-        cor_m = np.zeros((K_pad, W), bool)
-        rnd_arr = np.zeros((K_pad,), np.int32)
-        jitters = np.ones((K_pad, W))
-        recov = np.zeros((K_pad, W), np.float32)
-        drmat = np.ones((K_pad, W))
-        steps_a = np.zeros((K_pad, W), np.int64)
-        steps_b = np.zeros((K_pad, W), np.int64)
-        active_list: List[List[int]] = []
-        prune_now_rounds: List[np.ndarray] = []
+            # ---- host pre-compute: plans / budgets / jitter, in the lazy
+            # loop's exact env.rng order (plans then jitter, per round) --------
+            plans_a = np.zeros((K_pad, W, pad_a, batch), np.int64)
+            valid_a = np.zeros((K_pad, W, pad_a), np.float32)
+            plans_b = np.zeros((K_pad, W, max(pad_b, 1), batch), np.int64)
+            valid_b = np.zeros((K_pad, W, max(pad_b, 1)), np.float32)
+            budgets = np.zeros((K_pad, W), np.int32)
+            prune_any = np.zeros((K_pad,), bool)
+            real = np.zeros((K_pad,), bool)
+            weights = np.zeros((K_pad, W), np.float32)
+            submit_m = np.zeros((K_pad, W), np.float32)
+            mult_m = np.zeros((K_pad, W), np.float32)
+            byz_m = np.zeros((K_pad, W), bool)
+            cor_m = np.zeros((K_pad, W), bool)
+            rnd_arr = np.zeros((K_pad,), np.int32)
+            jitters = np.ones((K_pad, W))
+            recov = np.zeros((K_pad, W), np.float32)
+            drmat = np.ones((K_pad, W))
+            steps_a = np.zeros((K_pad, W), np.int64)
+            steps_b = np.zeros((K_pad, W), np.int64)
+            active_list: List[List[int]] = []
+            prune_now_rounds: List[np.ndarray] = []
 
-        for j, rnd in enumerate(rounds_this):
-            ev = plan_all.events[rnd - 1]
-            active_ws = [int(w) for w in np.flatnonzero(ev.active)]
-            active_list.append(active_ws)
-            if scen is not None:
-                scen_rows.append((
-                    rnd, len(active_ws),
-                    int(ev.dropped.sum()), int(ev.joined.sum()),
-                ))
-            # crash recovery rides the scan: a 1.0 in recov[j, w] zeroes the
-            # worker's momentum/DGC-residual rows at the top of round j's
-            # scan step — the in-scan mirror of the lazy loop's host-side
-            # zero_momentum_rows/residual reset.  Applies on skip rounds too
-            # (the lazy loop does its recovery bookkeeping before skipping).
-            if ev.recovered is not None:
-                recov[j] = ev.recovered.astype(np.float32)
-            if (scen is not None and scen.cfg.faults is not None
-                    and scen.cfg.faults.drift is not None):
-                drmat[j] = scen.drift_mults(rnd)
-            if ev.skip:
-                # degraded-floor round: rides the scan as a dead round
-                # (real=False, all-zero valid/submitters → the global carry
-                # passes through untouched).  The lazy skip branch draws no
-                # plans/jitter and resets no pending rates, so neither does
-                # this one: zero env.rng draws either way.
-                prune_now_rounds.append(np.zeros(W, bool))
-                continue
-            pa: List[Optional[np.ndarray]] = [None] * W
-            pb: List[Optional[np.ndarray]] = [None] * W
-            pn = np.zeros(W, bool)
-            for w in active_ws:
-                rate = pending_rates[w] if adapt else 0.0
-                if adapt and rate > 0.0:
-                    e1 = sim.beta * sim.local_epochs
-                    e2 = (1 - sim.beta) * sim.local_epochs
-                    pn[w] = True
-                else:
-                    e1, e2 = sim.local_epochs, 0.0
-                nsh = len(env.shards[w])
-                pa[w] = make_batch_plan(nsh, batch, e1, env.rng)
-                pb[w] = make_batch_plan(nsh, batch, e2, env.rng)
-                steps_a[j, w] = pa[w].shape[0]
-                steps_b[j, w] = pb[w].shape[0]
-            prune_now_rounds.append(pn)
-            for w in active_ws:
-                if pn[w]:
-                    budgets[j, w] = prune_budget_units(
-                        indices[w], pending_rates[w], env.space
-                    )
-            prune_any[j] = bool(pn.any())
-            sa = stack_batch_plans(pa, num_rows=W, num_steps=pad_a)
-            if sa is not None:
-                plans_a[j], valid_a[j] = sa
-            if pad_b > 0:
-                sb = stack_batch_plans(pb, num_rows=W, num_steps=pad_b)
-                if sb is not None:
-                    plans_b[j], valid_b[j] = sb
-            submit_m[j] = ev.submitters.astype(np.float32)
-            # commit multiplicity: submitters x delivery x duplication.  With
-            # no channel this IS the submitter indicator, so the f64 division
-            # below matches the pre-feature weights bit-for-bit.
-            mult_j = ev.submitters.astype(np.float64)
-            if ev.delivered is not None:
-                mult_j = mult_j * ev.delivered * (1.0 + ev.dup)
-            mult_m[j] = mult_j.astype(np.float32)
-            if sim.aggregation != "by_unit":
-                ms = mult_j.sum()
-                if ms > 0:
-                    weights[j] = (mult_j / ms).astype(np.float32)
-            if ev.byz is not None:
-                byz_m[j] = ev.byz & ev.submitters
-            if corrupt_on and ev.corrupt is not None:
-                cor_m[j] = ev.corrupt & ev.delivered & ev.submitters
-            rnd_arr[j] = rnd
-            real[j] = True
-            if sim.time_jitter > 0:
+            for j, rnd in enumerate(rounds_this):
+                ev = plan_all.events[rnd - 1]
+                active_ws = [int(w) for w in np.flatnonzero(ev.active)]
+                active_list.append(active_ws)
+                if scen is not None:
+                    scen_rows.append((
+                        rnd, len(active_ws),
+                        int(ev.dropped.sum()), int(ev.joined.sum()),
+                    ))
+                # crash recovery rides the scan: a 1.0 in recov[j, w] zeroes the
+                # worker's momentum/DGC-residual rows at the top of round j's
+                # scan step — the in-scan mirror of the lazy loop's host-side
+                # zero_momentum_rows/residual reset.  Applies on skip rounds too
+                # (the lazy loop does its recovery bookkeeping before skipping).
+                if ev.recovered is not None:
+                    recov[j] = ev.recovered.astype(np.float32)
+                if (scen is not None and scen.cfg.faults is not None
+                        and scen.cfg.faults.drift is not None):
+                    drmat[j] = scen.drift_mults(rnd)
+                if ev.skip:
+                    # degraded-floor round: rides the scan as a dead round
+                    # (real=False, all-zero valid/submitters → the global carry
+                    # passes through untouched).  The lazy skip branch draws no
+                    # plans/jitter and resets no pending rates, so neither does
+                    # this one: zero env.rng draws either way.
+                    prune_now_rounds.append(np.zeros(W, bool))
+                    continue
+                pa: List[Optional[np.ndarray]] = [None] * W
+                pb: List[Optional[np.ndarray]] = [None] * W
+                pn = np.zeros(W, bool)
                 for w in active_ws:
-                    jitters[j, w] = float(
-                        np.exp(env.rng.normal(0, sim.time_jitter))
-                    )
-            for w in active_ws:      # submission resets the pending rate
-                pending_rates[w] = 0.0
+                    rate = pending_rates[w] if adapt else 0.0
+                    if adapt and rate > 0.0:
+                        e1 = sim.beta * sim.local_epochs
+                        e2 = (1 - sim.beta) * sim.local_epochs
+                        pn[w] = True
+                    else:
+                        e1, e2 = sim.local_epochs, 0.0
+                    nsh = len(env.shards[w])
+                    pa[w] = make_batch_plan(nsh, batch, e1, env.rng)
+                    pb[w] = make_batch_plan(nsh, batch, e2, env.rng)
+                    steps_a[j, w] = pa[w].shape[0]
+                    steps_b[j, w] = pb[w].shape[0]
+                prune_now_rounds.append(pn)
+                for w in active_ws:
+                    if pn[w]:
+                        budgets[j, w] = prune_budget_units(
+                            indices[w], pending_rates[w], env.space
+                        )
+                prune_any[j] = bool(pn.any())
+                sa = stack_batch_plans(pa, num_rows=W, num_steps=pad_a)
+                if sa is not None:
+                    plans_a[j], valid_a[j] = sa
+                if pad_b > 0:
+                    sb = stack_batch_plans(pb, num_rows=W, num_steps=pad_b)
+                    if sb is not None:
+                        plans_b[j], valid_b[j] = sb
+                submit_m[j] = ev.submitters.astype(np.float32)
+                # commit multiplicity: submitters x delivery x duplication.  With
+                # no channel this IS the submitter indicator, so the f64 division
+                # below matches the pre-feature weights bit-for-bit.
+                mult_j = ev.submitters.astype(np.float64)
+                if ev.delivered is not None:
+                    mult_j = mult_j * ev.delivered * (1.0 + ev.dup)
+                mult_m[j] = mult_j.astype(np.float32)
+                if sim.aggregation != "by_unit":
+                    ms = mult_j.sum()
+                    if ms > 0:
+                        weights[j] = (mult_j / ms).astype(np.float32)
+                if ev.byz is not None:
+                    byz_m[j] = ev.byz & ev.submitters
+                if corrupt_on and ev.corrupt is not None:
+                    cor_m[j] = ev.corrupt & ev.delivered & ev.submitters
+                rnd_arr[j] = rnd
+                real[j] = True
+                if sim.time_jitter > 0:
+                    for w in active_ws:
+                        jitters[j, w] = float(
+                            np.exp(env.rng.normal(0, sim.time_jitter))
+                        )
+                for w in active_ws:      # submission resets the pending rate
+                    pending_rates[w] = 0.0
 
-        orders_np = None
-        if sim.importance in STATIC_METHODS:
-            orders_np = _static_orders(sim, env, flat, cig_scores,
-                                       prune_round_count)
-        orders_dev = jnp.asarray(
-            orders_np if orders_np is not None
-            else np.zeros((W, U), np.int32)
-        )
-        presence_dev = jnp.asarray(
-            np.stack([presence_from_index(indices[w], flat) for w in range(W)])
-        )
-        per_round = {
-            "plan_a": jnp.asarray(plans_a.astype(np.int32)),
-            "valid_a": jnp.asarray(valid_a),
-            "budgets": jnp.asarray(budgets),
-            "prune_any": jnp.asarray(prune_any),
-            "real": jnp.asarray(real),
-            "weights": jnp.asarray(weights),
-            "submitters": jnp.asarray(submit_m),
-            "recov": jnp.asarray(recov),
-        }
-        if pad_b > 0:
-            per_round["plan_b"] = jnp.asarray(plans_b.astype(np.int32))
-            per_round["valid_b"] = jnp.asarray(valid_b)
-        if robust_on:
-            per_round["mult"] = jnp.asarray(mult_m)
-            per_round["rnd"] = jnp.asarray(rnd_arr)
-            if byz_cfg is not None:
-                per_round["byz"] = jnp.asarray(byz_m)
-            if corrupt_on:
-                per_round["corrupt"] = jnp.asarray(cor_m)
-        momentum_arg = state.momentum if sim.resident_momentum else {}
+            orders_np = None
+            if sim.importance in STATIC_METHODS:
+                orders_np = _static_orders(sim, env, flat, cig_scores,
+                                           prune_round_count)
+            orders_dev = jnp.asarray(
+                orders_np if orders_np is not None
+                else np.zeros((W, U), np.int32)
+            )
+            presence_dev = jnp.asarray(
+                np.stack([presence_from_index(indices[w], flat) for w in range(W)])
+            )
+            per_round = {
+                "plan_a": jnp.asarray(plans_a.astype(np.int32)),
+                "valid_a": jnp.asarray(valid_a),
+                "budgets": jnp.asarray(budgets),
+                "prune_any": jnp.asarray(prune_any),
+                "real": jnp.asarray(real),
+                "weights": jnp.asarray(weights),
+                "submitters": jnp.asarray(submit_m),
+                "recov": jnp.asarray(recov),
+            }
+            if pad_b > 0:
+                per_round["plan_b"] = jnp.asarray(plans_b.astype(np.int32))
+                per_round["valid_b"] = jnp.asarray(valid_b)
+            if robust_on:
+                per_round["mult"] = jnp.asarray(mult_m)
+                per_round["rnd"] = jnp.asarray(rnd_arr)
+                if byz_cfg is not None:
+                    per_round["byz"] = jnp.asarray(byz_m)
+                if corrupt_on:
+                    per_round["corrupt"] = jnp.asarray(cor_m)
+            momentum_arg = state.momentum if sim.resident_momentum else {}
 
         # ---- ONE device dispatch for the whole chunk ---------------------
-        (state.params, mom_out, _, global_dev, dgc_res_dev, health_dev,
-         pres_seq, glob_seq, kept_seq, total_seq, quar_seq) = (
-            trainer._call_cached(
-                sig, build,
-                state.params, momentum_arg, presence_dev, global_dev,
-                dgc_res_dev, health_dev, state.xs, state.ys, sizes_dev,
-                per_round, orders_dev,
+        with span("chunk.dispatch"):
+            (state.params, mom_out, _, global_dev, dgc_res_dev, health_dev,
+             pres_seq, glob_seq, kept_seq, total_seq, quar_seq) = (
+                trainer._call_cached(
+                    sig, build,
+                    state.params, momentum_arg, presence_dev, global_dev,
+                    dgc_res_dev, health_dev, state.xs, state.ys, sizes_dev,
+                    per_round, orders_dev,
+                )
             )
-        )
         if sim.resident_momentum:
             state.momentum = mom_out
         fused_chunks += 1
         env.fleet.batched_calls += 1
         env.fleet.buckets_used.add(W)
 
-        pres_seq_np = np.asarray(pres_seq)                     # [K, W, U]
-        glob_seq_np = {k: np.asarray(v) for k, v in glob_seq.items()}
-        if use_dgc:                                            # [K, W] ints
-            kept_np = np.asarray(kept_seq)
-            total_np = np.asarray(total_seq)
-        if quar_cfg is not None:                               # [K, W] 0/1
-            quar_np = np.asarray(quar_seq)
+        with span("chunk.readback"):
+            pres_seq_np = np.asarray(pres_seq)                     # [K, W, U]
+            glob_seq_np = {k: np.asarray(v) for k, v in glob_seq.items()}
+            if use_dgc:                                            # [K, W] ints
+                kept_np = np.asarray(kept_seq)
+                total_np = np.asarray(total_seq)
+            if quar_cfg is not None:                               # [K, W] 0/1
+                quar_np = np.asarray(quar_seq)
 
-        # ---- post-chunk host accounting (payloads, clock, ledger, eval) --
-        for j, rnd in enumerate(rounds_this):
-            ev = plan_all.events[rnd - 1]
-            active_ws = active_list[j]
-            pn = prune_now_rounds[j]
-            if ev.skip:
-                # degraded floor: the global is untouched (dead scan round),
-                # the virtual clock waits out the straggler deadline, no
-                # update times land.  Evals still fire — glob_seq[j] is the
-                # pass-through carry, identical to the lazy skip branch's
-                # unchanged global_params.
-                clock += _skip_round_time(env, scen, indices, rnd)
-                upd_times.append([float("nan")] * W)
+        # ---- post-chunk host accounting (payloads, clock, ledger); the
+        # rounds' evaluations follow it, in round order ------------------
+        evals = []
+        with span("chunk.account"):
+            for j, rnd in enumerate(rounds_this):
+                ev = plan_all.events[rnd - 1]
+                active_ws = active_list[j]
+                pn = prune_now_rounds[j]
+                if ev.skip:
+                    # degraded floor: the global is untouched (dead scan round),
+                    # the virtual clock waits out the straggler deadline, no
+                    # update times land.  Evals still fire — glob_seq[j] is the
+                    # pass-through carry, identical to the lazy skip branch's
+                    # unchanged global_params.
+                    clock += _skip_round_time(env, scen, indices, rnd)
+                    upd_times.append([float("nan")] * W)
+                    if rnd % sim.eval_every == 0:
+                        evals.append((clock, j))
+                    continue
+                for w in active_ws:     # ledger phase A at the pre-prune index
+                    env.account_train(indices[w], int(steps_a[j, w]))
+                for w in active_ws:
+                    if pn[w]:
+                        indices[w] = index_from_presence(pres_seq_np[j, w], flat)
+                        prune_events.append((
+                            rnd, int(w),
+                            {k: tuple(map(int, v)) for k, v in indices[w].items()},
+                        ))
+                        if pad_b > 0:   # ledger phase B at the pruned index
+                            env.account_train(indices[w], int(steps_b[j, w]))
+                if quar_cfg is not None:
+                    # commits excluded by the server this round: quarantined row
+                    # AND a payload actually arrived (mult > 0)
+                    quarantined_commits += int(
+                        ((quar_np[j] > 0.5) & (mult_m[j] > 0)).sum()
+                    )
+                phis = np.full(W, np.nan)
+                for w in active_ws:
+                    bytes_w, flops_w = _bytes_flops(indices[w])
+                    # the host path's exact DGC payload factor, rebuilt from the
+                    # realized on-device kept/total integers (submitters only —
+                    # non-submitters pay full price, matching _run_sync)
+                    pf = 1.0
+                    if use_dgc and ev.submitters[w]:
+                        pf = 1.25 * float(kept_np[j, w]) / max(
+                            float(total_np[j, w]), 1.0
+                        )
+                    # jitter x drift multiplied HERE (one float product) so the
+                    # value is bit-identical to the lazy path's
+                    # phi_from_cost(..., jmult * time_mult); channel retries
+                    # stretch the drift factor FIRST (d*r), then jitter — the
+                    # masked loop associates its floats the same way.
+                    retry_mult = 1.0
+                    if (ch_cfg is not None and ev.retries is not None
+                            and ev.submitters[w]):
+                        retry_mult = (
+                            1.0 + ch_cfg.retry_backoff * float(ev.retries[w])
+                        )
+                    phi_w = env.phi_from_cost(
+                        w, bytes_w, flops_w, pf,
+                        jitters[j, w] * (drmat[j, w] * retry_mult),
+                    )
+                    phis[w] = phi_w
+                    interval_phis[w].append(phi_w)
+                    if ev.submitters[w]:
+                        extra = 0.0
+                        if ch_cfg is not None and ev.retries is not None:
+                            extra = (
+                                float(ev.retries[w])
+                                + float(ev.dup[w] & ev.delivered[w])
+                            ) * pf * bytes_w
+                        comm_bytes += 2.0 * pf * bytes_w + extra
+                sub_phis = phis[ev.submitters]
+                round_time = float(sub_phis.max())
+                if ev.dropped.any() and scen is not None:
+                    round_time *= scen.cfg.timeout_factor
+                clock += round_time
+                upd_times.append(list(phis))
+                het_traj.append((rnd, heterogeneity_from_times(sub_phis)))
+                if W > 3:
+                    sim_traj.append((rnd, similarity(indices[1], indices[3])))
                 if rnd % sim.eval_every == 0:
-                    g_j = {k: v[j] for k, v in glob_seq_np.items()}
-                    acc_time.append((clock, _env_accuracy(env, g_j)))
-                continue
-            for w in active_ws:     # ledger phase A at the pre-prune index
-                env.account_train(indices[w], int(steps_a[j, w]))
-            for w in active_ws:
-                if pn[w]:
-                    indices[w] = index_from_presence(pres_seq_np[j, w], flat)
-                    prune_events.append((
-                        rnd, int(w),
-                        {k: tuple(map(int, v)) for k, v in indices[w].items()},
-                    ))
-                    if pad_b > 0:   # ledger phase B at the pruned index
-                        env.account_train(indices[w], int(steps_b[j, w]))
-            if quar_cfg is not None:
-                # commits excluded by the server this round: quarantined row
-                # AND a payload actually arrived (mult > 0)
-                quarantined_commits += int(
-                    ((quar_np[j] > 0.5) & (mult_m[j] > 0)).sum()
-                )
-            phis = np.full(W, np.nan)
-            for w in active_ws:
-                bytes_w, flops_w = _bytes_flops(indices[w])
-                # the host path's exact DGC payload factor, rebuilt from the
-                # realized on-device kept/total integers (submitters only —
-                # non-submitters pay full price, matching _run_sync)
-                pf = 1.0
-                if use_dgc and ev.submitters[w]:
-                    pf = 1.25 * float(kept_np[j, w]) / max(
-                        float(total_np[j, w]), 1.0
-                    )
-                # jitter x drift multiplied HERE (one float product) so the
-                # value is bit-identical to the lazy path's
-                # phi_from_cost(..., jmult * time_mult); channel retries
-                # stretch the drift factor FIRST (d*r), then jitter — the
-                # masked loop associates its floats the same way.
-                retry_mult = 1.0
-                if (ch_cfg is not None and ev.retries is not None
-                        and ev.submitters[w]):
-                    retry_mult = (
-                        1.0 + ch_cfg.retry_backoff * float(ev.retries[w])
-                    )
-                phi_w = env.phi_from_cost(
-                    w, bytes_w, flops_w, pf,
-                    jitters[j, w] * (drmat[j, w] * retry_mult),
-                )
-                phis[w] = phi_w
-                interval_phis[w].append(phi_w)
-                if ev.submitters[w]:
-                    extra = 0.0
-                    if ch_cfg is not None and ev.retries is not None:
-                        extra = (
-                            float(ev.retries[w])
-                            + float(ev.dup[w] & ev.delivered[w])
-                        ) * pf * bytes_w
-                    comm_bytes += 2.0 * pf * bytes_w + extra
-            sub_phis = phis[ev.submitters]
-            round_time = float(sub_phis.max())
-            if ev.dropped.any() and scen is not None:
-                round_time *= scen.cfg.timeout_factor
-            clock += round_time
-            upd_times.append(list(phis))
-            het_traj.append((rnd, heterogeneity_from_times(sub_phis)))
-            if W > 3:
-                sim_traj.append((rnd, similarity(indices[1], indices[3])))
-            if rnd % sim.eval_every == 0:
-                g_j = {k: v[j] for k, v in glob_seq_np.items()}
-                acc_time.append((clock, _env_accuracy(env, g_j)))
+                    evals.append((clock, j))
+        for clock_j, j in evals:
+            g_j = {k: v[j] for k, v in glob_seq_np.items()}
+            acc_time.append((clock_j, _env_accuracy(env, g_j)))
         global_params = {k: np.array(v[n - 1]) for k, v in glob_seq_np.items()}
         t += n
 
@@ -1011,40 +1031,39 @@ def run_sync_fused(sim, env):
         # after the capability changed — same timing as the lazy loop.
         drift_now = scen is not None and scen.drift_changed(t)
         if adapt and (t % sim.prune_interval == 0 or drift_now):
-            t0 = _time.perf_counter()
-            prune_round_count += 1
-            if cig_scores is None and sim.importance == "cig_bnscalor":
-                cig_scores = METHODS["cig_bnscalor"](ImportanceContext(
-                    unit_counts=env.space.unit_counts,
-                    scales=extract_bn_scales(global_params, sim.cnn),
-                ))
-            if drift_now:
-                histories[sim.scenario.faults.drift.worker].invalidate()
-            mults = scen.drift_mults(t) if scen is not None else np.ones(W)
-            gammas_now = [retention(indices[w], env.space) for w in range(W)]
-            phis_now = [
-                float(np.mean(interval_phis[w])) if interval_phis[w]
-                else env.phi_from_index(
-                    w, indices[w], jitter=False, time_mult=float(mults[w])
-                )
-                for w in range(W)
-            ]
-            for w in range(W):
-                histories[w].record(gammas_now[w], phis_now[w])
-            if sim.fixed_pruned_rates is not None:
-                k = prune_round_count - 1
-                rates = (
-                    sim.fixed_pruned_rates[k]
-                    if k < len(sim.fixed_pruned_rates)
-                    else [0.0] * W
-                )
-            else:
-                rates = learn_pruned_rates(
-                    histories, gammas_now, phis_now, sim.rate_cfg
-                )
-            pending_rates = list(rates)
-            interval_phis = [[] for _ in range(W)]
-            server_overhead += _time.perf_counter() - t0
+            with span("alg2"):
+                prune_round_count += 1
+                if cig_scores is None and sim.importance == "cig_bnscalor":
+                    cig_scores = METHODS["cig_bnscalor"](ImportanceContext(
+                        unit_counts=env.space.unit_counts,
+                        scales=extract_bn_scales(global_params, sim.cnn),
+                    ))
+                if drift_now:
+                    histories[sim.scenario.faults.drift.worker].invalidate()
+                mults = scen.drift_mults(t) if scen is not None else np.ones(W)
+                gammas_now = [retention(indices[w], env.space) for w in range(W)]
+                phis_now = [
+                    float(np.mean(interval_phis[w])) if interval_phis[w]
+                    else env.phi_from_index(
+                        w, indices[w], jitter=False, time_mult=float(mults[w])
+                    )
+                    for w in range(W)
+                ]
+                for w in range(W):
+                    histories[w].record(gammas_now[w], phis_now[w])
+                if sim.fixed_pruned_rates is not None:
+                    k = prune_round_count - 1
+                    rates = (
+                        sim.fixed_pruned_rates[k]
+                        if k < len(sim.fixed_pruned_rates)
+                        else [0.0] * W
+                    )
+                else:
+                    rates = learn_pruned_rates(
+                        histories, gammas_now, phis_now, sim.rate_cfg
+                    )
+                pending_rates = list(rates)
+                interval_phis = [[] for _ in range(W)]
 
     host_roundtrips = roundtrip_total() - rt_base
     final_costs = [env.cost_for_index(indices[w]) for w in range(W)]
@@ -1053,7 +1072,7 @@ def run_sync_fused(sim, env):
         [retention(indices[w], env.space) for w in range(W)],
         [extract_subparams(global_params, indices[w], unit_map)
          for w in range(W)],
-        comm_bytes, server_overhead, clock,
+        comm_bytes, clock,
         global_params=global_params, host_roundtrips=host_roundtrips,
         scenario_rounds=scen_rows,
         flops_per_image_final=float(np.mean([c[0] for c in final_costs])),
@@ -1275,7 +1294,7 @@ def run_async_fused(sim, env, scen, participants, plan):
     E = plan.num_events
     if E == 0:
         return _finalize(sim, env, acc_time, [], [], [], [1.0] * W,
-                         [dict(global_params) for _ in range(W)], 0.0, 0.0,
+                         [dict(global_params) for _ in range(W)], 0.0,
                          0.0, global_params=dict(global_params),
                          host_roundtrips=roundtrip_total() - rt_base,
                          scenario_rounds=(
@@ -1460,7 +1479,7 @@ def run_async_fused(sim, env, scen, participants, plan):
         if quar_cfg is not None else 0
     )
     return _finalize(sim, env, acc_time, [], [], [], [1.0] * W,
-                     [dict(global_params) for _ in range(W)], comm_bytes, 0.0,
+                     [dict(global_params) for _ in range(W)], comm_bytes,
                      clock, global_params=dict(global_params),
                      host_roundtrips=host_roundtrips,
                      scenario_rounds=scen_rows,

@@ -84,13 +84,19 @@ the participants.
 plus per-worker async merge copies inside the loop — 0 for the resident
 engine), and ``bucket_sizes`` (the sub-stack row buckets launched, which
 bound the recompile count) so the engines' host cost can be compared
-directly.
+directly.  ``span_s`` and ``span_n`` hold the seconds and counts of the
+run's named host spans and compile events (``core.spans``): ``sim`` (the
+whole run), ``setup``, ``evaluate``, ``first_call.<program>``, ``alg2``,
+host ``aggregate`` (the per-round engines), the fused engine's
+``chunk.plan`` / ``chunk.dispatch`` / ``chunk.readback`` /
+``chunk.account``, and ``compile.trace_lower`` / ``compile.load`` /
+``compile.cache_miss``; ``walltime_s``, ``compile_walltime_s`` and
+``server_overhead_s`` are read off them.
 """
 from __future__ import annotations
 
 import dataclasses
 import heapq
-import time as _time
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -155,6 +161,7 @@ from .scenario import (
     ScenarioEngine,
     full_participation,
 )
+from .spans import collect, span
 from .timing import HeterogeneityConfig, heterogeneity_from_times, make_bandwidths
 from .worker import LocalTrainer, local_unit_stats, make_batch_plan, plan_steps
 
@@ -296,7 +303,7 @@ class SimResult:
     param_reduction: float                       # avg over workers
     flops_reduction: float
     comm_bytes: float
-    server_overhead_s: float                     # Alg.2 + aggregation walltime
+    server_overhead_s: float                     # Alg.2 + host aggregation walltime (spans)
     recompiles: int
     similarity_traj: List[Tuple[int, float]]     # Eq. 3 between two workers
     update_times: List[List[float]]              # per round, per worker
@@ -339,7 +346,8 @@ class SimResult:
     # O(rounds / round_fusion) — the companion metric to host_roundtrips
     host_dispatches: int = 0
     # wall spent inside FIRST calls of each compiled signature (trace +
-    # compile + one execution) — subtract from walltime_s for steady-state
+    # compile + one execution; the first_call.* spans) — subtract from
+    # walltime_s for steady-state
     compile_walltime_s: float = 0.0
     # fused engine: number of lax.scan chunk programs launched
     fused_chunks: int = 0
@@ -373,6 +381,10 @@ class SimResult:
     quarantined_commits: int = 0
     # final global model (base coordinates) — test/analysis hook
     global_params: Optional[Dict[str, np.ndarray]] = None
+    # wall seconds and counts of the run's named host spans and compile
+    # events (core.spans), by name; nested spans are inside their parents
+    span_s: Dict[str, float] = dataclasses.field(default_factory=dict)
+    span_n: Dict[str, int] = dataclasses.field(default_factory=dict)
 
 
 def _env_accuracy(env: "_Env", params) -> float:
@@ -383,18 +395,19 @@ def _env_accuracy(env: "_Env", params) -> float:
     ``compile_walltime_s`` stay honest across engines."""
     cfg = env.sim.cnn
     x, y = env.task.x_test, env.task.y_test
-    jp = {k: jnp.asarray(v) for k, v in params.items()}
-    correct = 0
-    for i in range(0, len(x), 256):
-        xb = x[i : i + 256]
-        logits = env.trainer._call_cached(
-            ("eval_logits", xb.shape),
-            lambda: jax.jit(lambda p, q: cnn_apply(p, cfg, q)),
-            jp, jnp.asarray(xb),
-            count_compile=False,
-        )
-        correct += int((np.argmax(np.asarray(logits), -1) == y[i : i + 256]).sum())
-    return correct / len(x)
+    with span("evaluate"):
+        jp = {k: jnp.asarray(v) for k, v in params.items()}
+        correct = 0
+        for i in range(0, len(x), 256):
+            xb = x[i : i + 256]
+            logits = env.trainer._call_cached(
+                ("eval_logits", xb.shape),
+                lambda: jax.jit(lambda p, q: cnn_apply(p, cfg, q)),
+                jp, jnp.asarray(xb),
+                count_compile=False,
+            )
+            correct += int((np.argmax(np.asarray(logits), -1) == y[i : i + 256]).sum())
+        return correct / len(x)
 
 
 class _Env:
@@ -955,7 +968,6 @@ def _run_sync(sim: SimConfig, env: _Env) -> SimResult:
 
     clock = 0.0
     comm_bytes = 0.0
-    server_overhead = 0.0
     acc_time, het_traj, sim_traj, upd_times = [], [], [], []
     scen_rows: List[Tuple[int, int, int, int]] = []
     events_log: List = []
@@ -969,36 +981,37 @@ def _run_sync(sim: SimConfig, env: _Env) -> SimResult:
         (gamma, phi) history first — those pairs describe a capability that
         no longer exists — so it re-enters through the bootstrap path."""
         nonlocal prune_round_count, cig_scores, pending_rates, interval_phis
-        prune_round_count += 1
-        if cig_scores is None and sim.importance == "cig_bnscalor":
-            cig_scores = METHODS["cig_bnscalor"](ImportanceContext(
-                unit_counts=env.space.unit_counts,
-                scales=extract_bn_scales(global_params, sim.cnn),
-            ))
-        if drift_trigger:
-            histories[sim.scenario.faults.drift.worker].invalidate()
-        mults = scen.drift_mults(t) if scen is not None else np.ones(W)
-        gammas_now = [retention(indices[w], env.space) for w in range(W)]
-        phis_now = [
-            float(np.mean(interval_phis[w])) if interval_phis[w]
-            else env.phi_from_index(
-                w, indices[w], jitter=False, time_mult=float(mults[w])
-            )
-            for w in range(W)
-        ]
-        for w in range(W):
-            histories[w].record(gammas_now[w], phis_now[w])
-        if sim.fixed_pruned_rates is not None:
-            k = prune_round_count - 1
-            rates = (
-                sim.fixed_pruned_rates[k]
-                if k < len(sim.fixed_pruned_rates)
-                else [0.0] * W
-            )
-        else:
-            rates = learn_pruned_rates(histories, gammas_now, phis_now, sim.rate_cfg)
-        pending_rates = list(rates)
-        interval_phis = [[] for _ in range(W)]
+        with span("alg2"):
+            prune_round_count += 1
+            if cig_scores is None and sim.importance == "cig_bnscalor":
+                cig_scores = METHODS["cig_bnscalor"](ImportanceContext(
+                    unit_counts=env.space.unit_counts,
+                    scales=extract_bn_scales(global_params, sim.cnn),
+                ))
+            if drift_trigger:
+                histories[sim.scenario.faults.drift.worker].invalidate()
+            mults = scen.drift_mults(t) if scen is not None else np.ones(W)
+            gammas_now = [retention(indices[w], env.space) for w in range(W)]
+            phis_now = [
+                float(np.mean(interval_phis[w])) if interval_phis[w]
+                else env.phi_from_index(
+                    w, indices[w], jitter=False, time_mult=float(mults[w])
+                )
+                for w in range(W)
+            ]
+            for w in range(W):
+                histories[w].record(gammas_now[w], phis_now[w])
+            if sim.fixed_pruned_rates is not None:
+                k = prune_round_count - 1
+                rates = (
+                    sim.fixed_pruned_rates[k]
+                    if k < len(sim.fixed_pruned_rates)
+                    else [0.0] * W
+                )
+            else:
+                rates = learn_pruned_rates(histories, gammas_now, phis_now, sim.rate_cfg)
+            pending_rates = list(rates)
+            interval_phis = [[] for _ in range(W)]
 
     for t in range(1, sim.rounds + 1):
         events = scen.draw(t) if scen is not None else full_participation(W)
@@ -1069,10 +1082,8 @@ def _run_sync(sim: SimConfig, env: _Env) -> SimResult:
         if events.skip:
             clock += _skip_round_time(env, scen, indices, t)
             upd_times.append([float("nan")] * W)
-            t0 = _time.perf_counter()
             if adapt and (t % sim.prune_interval == 0 or events.drift_changed):
                 _learn_rates(t, events.drift_changed)
-            server_overhead += _time.perf_counter() - t0
             if t % sim.eval_every == 0:
                 acc_time.append((clock, _env_accuracy(env, global_params)))
             continue
@@ -1247,85 +1258,84 @@ def _run_sync(sim: SimConfig, env: _Env) -> SimResult:
         if W > 3:
             sim_traj.append((t, similarity(indices[1], indices[3])))
 
-        t0 = _time.perf_counter()
-        if resident:
-            if agg_stacks is None:
-                agg_stacks = env.fleet.params_host(state)
-            if sim.aggregation == "by_unit":
-                global_params = aggregate_by_unit_stacked(
-                    agg_stacks, env.fleet.masks_host(state), submitters
-                )
-            elif robust_on:
+        with span("aggregate"):
+            if resident:
+                if agg_stacks is None:
+                    agg_stacks = env.fleet.params_host(state)
+                if sim.aggregation == "by_unit":
+                    global_params = aggregate_by_unit_stacked(
+                        agg_stacks, env.fleet.masks_host(state), submitters
+                    )
+                elif robust_on:
+                    mult = _commit_multiplicity(events)
+                    global_params, rb_strikes, rb_quar, quar_now = (
+                        _robust_aggregate_host(
+                            agg_stacks, env.fleet.masks_host(state), global_params,
+                            mult, events, byz_cfg, ch_cfg, corrupt_on, rb_cfg,
+                            sim.seed, t, rb_strikes, rb_quar,
+                        )
+                    )
+                    if quar_now is not None:
+                        quarantined_commits += int((quar_now & (mult > 0)).sum())
+                else:
+                    weights = submitters / submitters.sum()
+                    global_params = aggregate_by_worker_stacked(agg_stacks, weights)
+            elif robust_on and sim.aggregation != "by_unit":
+                # per-worker engines embed submissions into [W, ...] base stacks
+                # and run the SAME robust pipeline; rows without a commit carry a
+                # zero delta (their masked global), weight 0 and health-ineligible
                 mult = _commit_multiplicity(events)
+                stacks = {
+                    k: np.zeros((W,) + tuple(s), np.float32)
+                    for k, s in env.base_shapes.items()
+                }
+                stack_masks = {
+                    k: np.zeros((W,) + tuple(s), np.float32)
+                    for k, s in env.base_shapes.items()
+                }
+                for w in range(W):
+                    for k in stack_masks:
+                        stack_masks[k][w] = coordinate_mask(
+                            k, indices[w], env.unit_map, env.base_shapes
+                        )
+                    if w in worker_params:
+                        emb = embed_params(
+                            worker_params[w], indices[w], env.unit_map,
+                            env.base_shapes,
+                        )
+                        for k in stacks:
+                            stacks[k][w] = emb[k]
+                    else:
+                        for k in stacks:
+                            stacks[k][w] = (
+                                np.asarray(global_params[k], np.float32)
+                                * stack_masks[k][w]
+                            )
                 global_params, rb_strikes, rb_quar, quar_now = (
                     _robust_aggregate_host(
-                        agg_stacks, env.fleet.masks_host(state), global_params,
-                        mult, events, byz_cfg, ch_cfg, corrupt_on, rb_cfg,
+                        stacks, stack_masks, global_params, mult, events,
+                        byz_cfg, ch_cfg, corrupt_on, rb_cfg,
                         sim.seed, t, rb_strikes, rb_quar,
                     )
                 )
                 if quar_now is not None:
                     quarantined_commits += int((quar_now & (mult > 0)).sum())
             else:
-                weights = submitters / submitters.sum()
-                global_params = aggregate_by_worker_stacked(agg_stacks, weights)
-        elif robust_on and sim.aggregation != "by_unit":
-            # per-worker engines embed submissions into [W, ...] base stacks
-            # and run the SAME robust pipeline; rows without a commit carry a
-            # zero delta (their masked global), weight 0 and health-ineligible
-            mult = _commit_multiplicity(events)
-            stacks = {
-                k: np.zeros((W,) + tuple(s), np.float32)
-                for k, s in env.base_shapes.items()
-            }
-            stack_masks = {
-                k: np.zeros((W,) + tuple(s), np.float32)
-                for k, s in env.base_shapes.items()
-            }
-            for w in range(W):
-                for k in stack_masks:
-                    stack_masks[k][w] = coordinate_mask(
-                        k, indices[w], env.unit_map, env.base_shapes
+                submissions = [
+                    (worker_params[w], indices[w]) for w in active_ws if submitters[w]
+                ]
+                if sim.aggregation == "by_unit":
+                    global_params = aggregate_by_unit(
+                        submissions, env.unit_map, env.base_shapes
                     )
-                if w in worker_params:
-                    emb = embed_params(
-                        worker_params[w], indices[w], env.unit_map,
-                        env.base_shapes,
-                    )
-                    for k in stacks:
-                        stacks[k][w] = emb[k]
                 else:
-                    for k in stacks:
-                        stacks[k][w] = (
-                            np.asarray(global_params[k], np.float32)
-                            * stack_masks[k][w]
-                        )
-            global_params, rb_strikes, rb_quar, quar_now = (
-                _robust_aggregate_host(
-                    stacks, stack_masks, global_params, mult, events,
-                    byz_cfg, ch_cfg, corrupt_on, rb_cfg,
-                    sim.seed, t, rb_strikes, rb_quar,
-                )
-            )
-            if quar_now is not None:
-                quarantined_commits += int((quar_now & (mult > 0)).sum())
-        else:
-            submissions = [
-                (worker_params[w], indices[w]) for w in active_ws if submitters[w]
-            ]
-            if sim.aggregation == "by_unit":
-                global_params = aggregate_by_unit(
-                    submissions, env.unit_map, env.base_shapes
-                )
-            else:
-                global_params = aggregate_by_worker(
-                    submissions, env.unit_map, env.base_shapes
-                )
-        global_params = {k: v.astype(np.float32) for k, v in global_params.items()}
+                    global_params = aggregate_by_worker(
+                        submissions, env.unit_map, env.base_shapes
+                    )
+            global_params = {k: v.astype(np.float32) for k, v in global_params.items()}
 
         if adapt and (t % sim.prune_interval == 0 or events.drift_changed):
             _learn_rates(t, events.drift_changed)
-        server_overhead += _time.perf_counter() - t0
 
         if t % sim.eval_every == 0:
             acc_time.append((clock, _env_accuracy(env, global_params)))
@@ -1335,7 +1345,7 @@ def _run_sync(sim: SimConfig, env: _Env) -> SimResult:
     return _finalize(sim, env, acc_time, het_traj, sim_traj, upd_times,
                      [retention(indices[w], env.space) for w in range(W)],
                      [extract_subparams(global_params, indices[w], env.unit_map) for w in range(W)],
-                     comm_bytes, server_overhead, clock,
+                     comm_bytes, clock,
                      global_params=global_params, host_roundtrips=host_roundtrips,
                      scenario_rounds=scen_rows,
                      flops_per_image_final=float(np.mean([c[0] for c in final_costs])),
@@ -1725,7 +1735,7 @@ def _run_async(sim: SimConfig, env: _Env) -> SimResult:
     scen_rows = [(0, n_part, 0, 0)] if scen is not None else []
     final_cost = env.cost_for_index(idx)
     return _finalize(sim, env, acc_time, [], [], [], [1.0] * W,
-                     [dict(global_params) for _ in range(W)], comm_bytes, 0.0, clock,
+                     [dict(global_params) for _ in range(W)], comm_bytes, clock,
                      global_params=dict(global_params),
                      host_roundtrips=host_roundtrips,
                      scenario_rounds=scen_rows,
@@ -1738,7 +1748,7 @@ def _run_async(sim: SimConfig, env: _Env) -> SimResult:
 
 
 def _finalize(sim, env, acc_time, het_traj, sim_traj, upd_times, retentions,
-              worker_params, comm_bytes, server_overhead, clock,
+              worker_params, comm_bytes, clock,
               global_params=None, host_roundtrips=0,
               scenario_rounds=None, flops_per_image_final=0.0,
               blocks_per_image_final=0.0, prune_events=None,
@@ -1766,7 +1776,7 @@ def _finalize(sim, env, acc_time, het_traj, sim_traj, upd_times, retentions,
         param_reduction=1.0 - float(np.mean(param_sizes)) / full_size,
         flops_reduction=1.0 - float(np.mean(flops)) / env.full_flops,
         comm_bytes=comm_bytes,
-        server_overhead_s=server_overhead,
+        server_overhead_s=0.0,      # run_simulation reads it off the spans
         recompiles=env.trainer.compile_count,
         similarity_traj=sim_traj,
         update_times=upd_times,
@@ -1774,7 +1784,6 @@ def _finalize(sim, env, acc_time, het_traj, sim_traj, upd_times, retentions,
         batched_calls=env.fleet.batched_calls,
         host_roundtrips=host_roundtrips,
         host_dispatches=env.trainer.dispatch_count,
-        compile_walltime_s=env.trainer.compile_walltime_s,
         fused_chunks=fused_chunks,
         n_devices=stack_devices,
         fleet_axis_size=fleet_axis_size,
@@ -1798,18 +1807,25 @@ def _finalize(sim, env, acc_time, het_traj, sim_traj, upd_times, retentions,
 
 
 def run_simulation(sim: SimConfig) -> SimResult:
-    t0 = _time.perf_counter()
-    env = _Env(sim)
-    if sim.method in ("adaptcl", "fedavg", "fedavg_s"):
-        if sim.engine == "fused":
-            from .fused import run_sync_fused   # lazy: fused imports us back
+    with collect() as spans:
+        with span("sim"):
+            with span("setup"):
+                env = _Env(sim)
+            if sim.method in ("adaptcl", "fedavg", "fedavg_s"):
+                if sim.engine == "fused":
+                    from .fused import run_sync_fused   # lazy: fused imports us back
 
-            result = run_sync_fused(sim, env)
-        else:
-            result = _run_sync(sim, env)
-    elif sim.method in ("fedasync_s", "ssp_s", "dcasgd_s"):
-        result = _run_async(sim, env)   # routes engine == "fused" itself
-    else:
-        raise ValueError(f"unknown method {sim.method}")
-    result.walltime_s = _time.perf_counter() - t0
+                    result = run_sync_fused(sim, env)
+                else:
+                    result = _run_sync(sim, env)
+            elif sim.method in ("fedasync_s", "ssp_s", "dcasgd_s"):
+                result = _run_async(sim, env)   # routes engine == "fused" itself
+            else:
+                raise ValueError(f"unknown method {sim.method}")
+    result.span_s, result.span_n = dict(spans.seconds), dict(spans.counts)
+    result.walltime_s = spans.seconds["sim"]
+    result.compile_walltime_s = spans.total("first_call.")
+    result.server_overhead_s = (
+        spans.seconds.get("aggregate", 0.0) + spans.seconds.get("alg2", 0.0)
+    )
     return result

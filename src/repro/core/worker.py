@@ -28,7 +28,6 @@ that is what makes the engines numerically equivalent.
 from __future__ import annotations
 
 import dataclasses
-import time as _time
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
@@ -40,6 +39,7 @@ from repro.optim.group_lasso import group_lasso_penalty, group_size_sqrt
 from repro.optim.optimizers import apply_updates, momentum
 
 from .masks import GlobalIndex, prune_to_budget
+from .spans import span
 
 __all__ = [
     "LocalTrainer",
@@ -146,6 +146,18 @@ def reslice_subparams(
     return out
 
 
+def sig_name(sig) -> str:
+    """The program a jit-cache signature keys: its leading name
+    (``eval_logits``, ``fused``, ``resident``, ``plan``, ...), else
+    ``call``."""
+    for part in sig[:2] if isinstance(sig, tuple) else ():
+        if isinstance(part, tuple) and part:
+            part = part[0]
+        if isinstance(part, str):
+            return part
+    return "call"
+
+
 class LocalTrainer:
     """Minibatch SGD(+momentum) with optional group-lasso sparse training.
 
@@ -185,7 +197,6 @@ class LocalTrainer:
         self._step_cache: Dict = {}
         self.compile_count = 0  # reconfigure-induced recompiles (overhead bench)
         self.dispatch_count = 0  # jitted training programs launched (host->device)
-        self.compile_walltime_s = 0.0  # wall spent in FIRST calls (compile + 1 run)
 
     # ---- jit-cache plumbing ----------------------------------------------
 
@@ -194,12 +205,13 @@ class LocalTrainer:
 
         Every call counts toward ``dispatch_count`` (the per-round host
         dispatch metric ``SimResult.host_dispatches`` reports); the FIRST
-        call of each signature is timed to completion (``block_until_ready``)
-        and accumulated into ``compile_walltime_s``, so benchmarks can
-        separate warm-up (trace + compile + one run) from steady-state
-        walltime.  ``count_compile=False`` keeps a signature out of
-        ``compile_count`` (``SimResult.recompiles`` means *training-program*
-        recompiles — evaluation helpers are timed but not counted there)."""
+        call of each signature runs to completion (``block_until_ready``)
+        inside the span ``first_call.<name>`` (``sig_name``), whose seconds
+        ``SimResult.compile_walltime_s`` sums, so benchmarks can separate
+        warm-up (trace + compile + one run) from steady-state walltime.
+        ``count_compile=False`` keeps a signature out of ``compile_count``
+        (``SimResult.recompiles`` means *training-program* recompiles —
+        evaluation helpers are timed but not counted there)."""
         entry = self._step_cache.get(sig)
         if entry is None:
             entry = [build(), False]
@@ -210,10 +222,9 @@ class LocalTrainer:
         fn, warm = entry
         if warm:
             return fn(*args)
-        t0 = _time.perf_counter()
-        out = fn(*args)
-        jax.block_until_ready(out)
-        self.compile_walltime_s += _time.perf_counter() - t0
+        with span(f"first_call.{sig_name(sig)}"):
+            out = fn(*args)
+            jax.block_until_ready(out)
         entry[1] = True
         return out
 
@@ -431,17 +442,23 @@ class LocalTrainer:
                 sel, v = step
                 vb = v > 0
                 q, st = carry
-                loss, grads = jax.value_and_grad(loss_fn)(q, x[sel], y[sel])
-                updates, st2 = opt.update(grads, st, q)
-                q2 = apply_updates(q, updates)
-                q = jax.tree.map(lambda a, b: jnp.where(vb, a, b), q2, q)
-                st = jax.tree.map(lambda a, b: jnp.where(vb, a, b), st2, st)
-                return (q, st), jnp.where(vb, loss, 0.0)
+                with jax.named_scope("train.step"):
+                    loss, grads = jax.value_and_grad(loss_fn)(q, x[sel], y[sel])
+                with jax.named_scope("train.update"):
+                    updates, st2 = opt.update(grads, st, q)
+                    q2 = apply_updates(q, updates)
+                with jax.named_scope("train.gate"):
+                    q = jax.tree.map(lambda a, b: jnp.where(vb, a, b), q2, q)
+                    st = jax.tree.map(lambda a, b: jnp.where(vb, a, b), st2, st)
+                    return (q, st), jnp.where(vb, loss, 0.0)
 
-            (p, opt_state), losses = jax.lax.scan(body, (p, opt_state), (plan, valid))
-            p = jax.tree.map(lambda w, m: w * m, p, mask)
-            steps = jnp.maximum(valid.sum(), 1.0)
-            return p, opt_state, losses.sum() / steps
+            # device scopes: the op names of a profile say which phase of
+            # the step an op belongs to (named scopes touch no fusion)
+            with jax.named_scope("train"):
+                (p, opt_state), losses = jax.lax.scan(body, (p, opt_state), (plan, valid))
+                p = jax.tree.map(lambda w, m: w * m, p, mask)
+                steps = jnp.maximum(valid.sum(), 1.0)
+                return p, opt_state, losses.sum() / steps
 
         return train_one
 

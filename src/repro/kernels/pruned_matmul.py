@@ -178,6 +178,7 @@ def _call(
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),
         interpret=interpret,
+        name="pruned_matmul",    # the kernel's name in traces and HLO
     )(m_keep, k_keep, n_keep, x, w,
       in_mask[None, :], out_mask[None, :], row_mask[:, None])
     return out[:M, :N]

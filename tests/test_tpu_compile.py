@@ -109,6 +109,21 @@ def test_pruned_matmul_compiles_for_v5e(one_chip, case):
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 10**9
 
 
+def test_kernel_carries_its_name(one_chip):
+    """The kernel keeps the name ``pruned_matmul`` whatever function calls
+    it: in the lowered custom call, and so in the compiled instruction a
+    trace reports."""
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def step(x, w, in_mask, out_mask):
+        return pruned_matmul(x, w, in_mask, out_mask) * 2.0
+
+    lowered = jax.jit(step).lower(sds((256, 256)), sds((256, 256)), sds((256,)), sds((256,)))
+    assert 'kernel_name = "pruned_matmul"' in lowered.as_text()
+    assert "%pruned_matmul" in lowered.compile().as_text()
+
+
 @pytest.mark.parametrize("env_dir", [None, "from-env"])
 def test_compile_cache_dir_rule(monkeypatch, tmp_path, env_dir):
     """``JAX_COMPILATION_CACHE_DIR`` wins and nothing is set in code;
