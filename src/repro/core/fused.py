@@ -249,7 +249,6 @@ def _build_chunk_fn(trainer, unit_map, base_shapes, flat: UnitFlat, lam,
         name: (int(flat.offsets[l]), int(flat.sizes[l]))
         for l, name in enumerate(flat.names)
     }
-    tiebreak_dev = jnp.asarray(flat.tiebreak)
 
     def counts_of(presence):
         return {
@@ -368,8 +367,12 @@ def _build_chunk_fn(trainer, unit_map, base_shapes, flat: UnitFlat, lam,
                         scores = device_scores(
                             params, masks, presence, xs, ys, sizes
                         )
+                        # the tie-break ranks enter as a constant of the
+                        # trace: the built program holds no device array
                         ow = jax.vmap(
-                            lambda s: jnp.lexsort((tiebreak_dev, s)).astype(jnp.int32)
+                            lambda s: jnp.lexsort(
+                                (jnp.asarray(flat.tiebreak), s)
+                            ).astype(jnp.int32)
                         )(scores)
                 with jax.named_scope("prune"):
                     pres2 = prune_presence_rows(presence, ow, inp["budgets"], flat)
@@ -660,7 +663,7 @@ def run_sync_fused(sim, env):
             sorted((k, tuple(v.shape)) for k, v in state.params.items())
         )
         mesh_sig = (
-            (sim.fleet_axis, int(mesh.shape[sim.fleet_axis]),
+            (sim.fleet_axis, tuple(mesh.axis_names), mesh.devices.shape,
              tuple(int(d.id) for d in mesh.devices.flat))
             if mesh is not None else None
         )
@@ -674,7 +677,8 @@ def run_sync_fused(sim, env):
               ((float(quar_cfg.threshold), int(quar_cfg.strikes),
                 int(quar_cfg.probation)) if quar_cfg is not None else None))
              if rb_cfg is not None else None),
-            int(sim.seed),
+            # the noise keys' seed is compiled in on the robust path only
+            int(sim.seed) if robust_on else None,
         )
         sig = (
             sig_shapes,
@@ -693,7 +697,8 @@ def run_sync_fused(sim, env):
             mesh=mesh, fleet_axis=sim.fleet_axis,
             robust=rb_cfg, byz=byz_cfg,
             corrupt_std=float(ch_cfg.corrupt_std) if corrupt_on else None,
-            channel=ch_cfg is not None, noise_seed=int(sim.seed),
+            channel=ch_cfg is not None,
+            noise_seed=int(sim.seed) if robust_on else 0,
             fleet_w=W if mesh is not None else None,
         )
         # quarantine health carry: full-fleet [W] rows, replicated on the mesh

@@ -78,9 +78,10 @@ update is discarded — no merge, no version bump, no communicated bytes);
 churn and scripted schedules stay sync-only.  Device compute is sized to
 the participants.
 
-``SimResult`` reports ``recompiles`` (jit shape-signatures compiled),
-``batched_calls`` (device programs launched by the batched engines),
-``walltime_s`` (host wall-clock), ``host_roundtrips`` (extract/embed calls
+``SimResult`` reports ``recompiles`` (the training programs' jit
+signatures the run called, built or found in the process's table of built
+programs), ``batched_calls`` (device programs launched by the batched
+engines), ``walltime_s`` (host wall-clock), ``host_roundtrips`` (extract/embed calls
 plus per-worker async merge copies inside the loop — 0 for the resident
 engine), and ``bucket_sizes`` (the sub-stack row buckets launched, which
 bound the recompile count) so the engines' host cost can be compared
@@ -89,9 +90,10 @@ run's named host spans and compile events (``core.spans``): ``sim`` (the
 whole run), ``setup``, ``evaluate``, ``first_call.<program>``, ``alg2``,
 host ``aggregate`` (the per-round engines), the fused engine's
 ``chunk.plan`` / ``chunk.dispatch`` / ``chunk.readback`` /
-``chunk.account``, and ``compile.trace_lower`` / ``compile.load`` /
-``compile.cache_miss``; ``walltime_s``, ``compile_walltime_s`` and
-``server_overhead_s`` are read off them.
+``chunk.account``, ``compile.trace_lower`` / ``compile.load`` /
+``compile.cache_miss``, and ``program_cache.hit`` / ``program_cache.miss``;
+``walltime_s``, ``compile_walltime_s`` and ``server_overhead_s`` are read
+off them.
 """
 from __future__ import annotations
 
@@ -1822,6 +1824,9 @@ def run_simulation(sim: SimConfig) -> SimResult:
                 result = _run_async(sim, env)   # routes engine == "fused" itself
             else:
                 raise ValueError(f"unknown method {sim.method}")
+    # a program in the table of built programs keeps its trainer alive (its
+    # closures hold it): the trainer's own cache lets go of the others
+    env.trainer._step_cache.clear()
     result.span_s, result.span_n = dict(spans.seconds), dict(spans.counts)
     result.walltime_s = spans.seconds["sim"]
     result.compile_walltime_s = spans.total("first_call.")

@@ -18,6 +18,10 @@ accumulator:
 * ``compile.cache_miss``: persistent-cache misses (a count in ``span_n``
   only).
 
+``count(name)`` adds a count alone: ``worker.LocalTrainer._call_cached``
+counts ``program_cache.hit`` and ``program_cache.miss``, its lookups of a
+new signature in the process's table of built programs.
+
 A trace nested inside another (a jitted helper traced while its caller is
 traced) is counted once, inside its caller's seconds.  With no simulation
 running, spans still annotate the profiler and count nothing.
@@ -30,7 +34,7 @@ from typing import Dict, Iterator, List, Optional, Tuple
 
 import jax
 
-__all__ = ["PREFIX", "Spans", "span", "collect"]
+__all__ = ["PREFIX", "Spans", "span", "count", "collect"]
 
 PREFIX = "adaptcl."
 
@@ -89,6 +93,13 @@ def span(name: str) -> Iterator[None]:
                 _current.add(name, time.perf_counter() - t0)
 
 
+def count(name: str) -> None:
+    """Count one ``name`` in the running simulation's ``span_n`` (with no
+    seconds)."""
+    if _current is not None:
+        _current.add(name, 0.0)
+
+
 def _on_time_span(event: str, start: float, end: float, **_) -> None:
     hit = _COMPILE_EVENTS.get(event)
     if hit is not None and _current is not None:
@@ -96,8 +107,8 @@ def _on_time_span(event: str, start: float, end: float, **_) -> None:
 
 
 def _on_event(event: str, **_) -> None:
-    if event == _CACHE_MISS and _current is not None:
-        _current.add("compile.cache_miss", 0.0)
+    if event == _CACHE_MISS:
+        count("compile.cache_miss")
 
 
 def _listen() -> None:

@@ -27,6 +27,7 @@ that is what makes the engines numerically equivalent.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
@@ -39,10 +40,12 @@ from repro.optim.group_lasso import group_lasso_penalty, group_size_sqrt
 from repro.optim.optimizers import apply_updates, momentum
 
 from .masks import GlobalIndex, prune_to_budget
-from .spans import span
+from .spans import count, span
 
 __all__ = [
     "LocalTrainer",
+    "PROGRAM_CACHE_SIZE",
+    "clear_program_cache",
     "make_batch_plan",
     "plan_steps",
     "stack_batch_plans",
@@ -158,6 +161,23 @@ def sig_name(sig) -> str:
     return "call"
 
 
+# Built programs shared by every trainer of the process, so that a sweep's
+# later simulations call the jitted functions (already traced, lowered and
+# loaded) of the earlier ones.  A key is the trainer's settings and the call
+# site's signature, which together hold every value a build's closure reads;
+# an entry is the jitted function alone, whose closures hold configuration
+# and host constants but no simulation's device arrays.  Least recently used
+# entries go first.
+PROGRAM_CACHE_SIZE = 16
+_programs: "collections.OrderedDict[tuple, object]" = collections.OrderedDict()
+
+
+def clear_program_cache() -> None:
+    """Forget every built program: the next call of each signature builds,
+    traces and loads it again."""
+    _programs.clear()
+
+
 class LocalTrainer:
     """Minibatch SGD(+momentum) with optional group-lasso sparse training.
 
@@ -193,6 +213,12 @@ class LocalTrainer:
 
             interpret = auto_interpret()
         self.compute_interpret = bool(interpret)
+        # what every build reads of the trainer: the first half of a key of
+        # the process's table of built programs
+        self._program_key = (
+            cnn_cfg, float(lr), float(beta), compute, self.compute_blocks,
+            self.compute_interpret,
+        )
         self._prunable = prunable_layer_names(cnn_cfg)
         self._step_cache: Dict = {}
         self.compile_count = 0  # reconfigure-induced recompiles (overhead bench)
@@ -203,18 +229,35 @@ class LocalTrainer:
     def _call_cached(self, sig, build, *args, count_compile: bool = True):
         """Dispatch a jitted program through the signature cache.
 
+        ``sig`` must hold every value ``build``'s closure reads beyond the
+        trainer's settings: a signature new to this trainer is looked up in
+        the process's table of built programs (``PROGRAM_CACHE_SIZE``
+        entries), and a program found there is called as it is, warm
+        (counted ``program_cache.hit`` in the spans; ``program_cache.miss``
+        otherwise).  A program built here enters the table once its first
+        call has completed.
+
         Every call counts toward ``dispatch_count`` (the per-round host
         dispatch metric ``SimResult.host_dispatches`` reports); the FIRST
-        call of each signature runs to completion (``block_until_ready``)
-        inside the span ``first_call.<name>`` (``sig_name``), whose seconds
-        ``SimResult.compile_walltime_s`` sums, so benchmarks can separate
-        warm-up (trace + compile + one run) from steady-state walltime.
-        ``count_compile=False`` keeps a signature out of ``compile_count``
-        (``SimResult.recompiles`` means *training-program* recompiles —
-        evaluation helpers are timed but not counted there)."""
+        call of each signature built here runs to completion
+        (``block_until_ready``) inside the span ``first_call.<name>``
+        (``sig_name``), whose seconds ``SimResult.compile_walltime_s`` sums,
+        so benchmarks can separate warm-up (trace + compile + one run) from
+        steady-state walltime.  ``count_compile=False`` keeps a signature out
+        of ``compile_count`` (``SimResult.recompiles`` means the distinct
+        *training-program* signatures a simulation called, found in the
+        table or not — evaluation helpers are not counted there)."""
+        key = (self._program_key, sig)
         entry = self._step_cache.get(sig)
         if entry is None:
-            entry = [build(), False]
+            fn = _programs.get(key)
+            if fn is not None:
+                _programs.move_to_end(key)
+                count("program_cache.hit")
+                entry = [fn, True]
+            else:
+                count("program_cache.miss")
+                entry = [build(), False]
             self._step_cache[sig] = entry
             if count_compile:
                 self.compile_count += 1
@@ -226,6 +269,9 @@ class LocalTrainer:
             out = fn(*args)
             jax.block_until_ready(out)
         entry[1] = True
+        _programs[key] = fn
+        while len(_programs) > PROGRAM_CACHE_SIZE:
+            _programs.popitem(last=False)
         return out
 
     def _masked_logits(self, qm, mask, xb):
