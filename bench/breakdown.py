@@ -6,9 +6,10 @@ program's own host spans and device scopes.
 The set-up and the traced simulation are those of ``bench/run.py --trace
 1``: a set-up simulation, then one simulation under the profiler with the
 same options and the harness's own spans; no reference is run.  The set-up
-simulation also keeps the training program's arguments; after the trace a
-fresh compile of it gives the HLO text that names each device operation's
-scope, which a TPU trace does not carry (``compiled_texts``).  Prints one
+simulation, on an emptied table of built programs, builds the training
+program and keeps it with its arguments; after the trace a fresh compile
+of it gives the HLO text that names each device operation's scope, which a
+TPU trace does not carry (``compiled_texts``).  Prints one
 JSON line and writes it, with the training program's device seconds per
 operation (``chunk_op_s``), to ``<out>/breakdown-<cell>-<seed>.json``, and
 the HLO text beside it (``.hlo.txt.gz``):
@@ -48,7 +49,7 @@ BOUNDARY = ("adaptcl.chunk.plan", "adaptcl.chunk.account", "adaptcl.alg2")
 
 @contextlib.contextmanager
 def first_calls(names: Iterable[str]):
-    """The jitted function and arguments of the first call of each program
+    """The jitted function and arguments of each program built (``run.builds``)
     whose jit-cache signature is named in ``names`` (``worker.sig_name``)."""
     from repro.core import worker
 
@@ -56,7 +57,7 @@ def first_calls(names: Iterable[str]):
     kept: List[tuple] = []
 
     def keep(self, sig, build, *args, **kw):
-        first = sig not in self._step_cache
+        first = run.builds(self, sig)
         out = call(self, sig, build, *args, **kw)
         if first and worker.sig_name(sig) in names:
             kept.append((self._step_cache[sig][0], args))
@@ -101,8 +102,13 @@ def profile_cell(wl: dict, cfg: dict, seed: int, trace_dir: Path,
     from bench import trace as tr
     from repro.core.simulation import run_simulation
 
+    from repro.core import worker
+
     task, pseed, _ = run.make_inputs(cfg, seed)
     sim = run.build_sim(wl, cfg, task, pseed)
+    # the set-up simulation builds every program, so that its build of the
+    # training chunk is kept for the HLO text
+    worker.clear_program_cache()
     with first_calls(("fused",)) as calls:
         run_simulation(sim)
     run._annotate_program(jax)

@@ -18,10 +18,10 @@ the reference, with ``K = TRAIN_ROUNDS``:
 * ``median_change_gap_r1``, ``median_change_gap_rK``: the same gaps, the
   median leaf's.
 * ``layer_change_gap_r1``, ``layer_change_gap_rK``: the same gap taken per
-  layer (a convolution or the head with its BN scale and shift: the leaves
-  that share a name up to the last ``/``), over the reference's norm for
-  that layer or for the median layer, whichever is larger; the worst layer
-  counts.
+  layer (the leaves that share a name up to the last ``/``: for the CNNs a
+  convolution or the head with its BN scale and shift), over the
+  reference's norm for that layer or for the median layer, whichever is
+  larger; the worst layer counts.
 * ``kept_units_mismatch``: over all prune events of the simulation, the
   largest share of a model's prunable units whose kept or pruned status
   differs (1 where the two sides pruned different workers or rounds).
@@ -127,7 +127,7 @@ def kept_units_mismatch(cand: Outcome, ref: Outcome) -> float:
         for layer, r in ridx.items():
             c = cidx.get(layer, np.zeros(0, np.int64))
             diff += len(set(map(int, r)) ^ set(map(int, c)))
-            total += int(ref.globals[0][f"{layer}/bn_g"].shape[0])
+            total += ref.unit_counts[layer]
         worst = max(worst, diff / total)
     return worst
 

@@ -1,8 +1,9 @@
-"""Seeded synthetic CIFAR-shaped data and the fleet's data partition.
+"""Seeded synthetic images and the fleet's data partition.
 
-The images follow the recipe of the simulator's own synthetic task (a class
-prototype of low-frequency noise plus per-image Gaussian noise), written here
-so that the benchmark, not the program, makes its inputs from ``--seed``.
+The images (``make_images``, which the CNN families' ``make_task`` calls)
+follow the recipe of the simulator's own synthetic task (a class prototype
+of low-frequency noise plus per-image Gaussian noise), written here so that
+the benchmark, not the program, makes its inputs from ``--seed``.
 The partition is the paper's Non-IID split (§IV-A): ``(1 - s%)`` of the data
 dealt IID, the rest sorted by label and dealt in contiguous chunks.
 """
@@ -16,8 +17,8 @@ import numpy as np
 NOISE = 0.6
 
 
-def make_task(num_classes: int, image_size: int, train_size: int,
-              test_size: int, seed: int) -> types.SimpleNamespace:
+def make_images(num_classes: int, image_size: int, train_size: int,
+                test_size: int, seed: int) -> types.SimpleNamespace:
     """Train and test images ``[n, s, s, 3]`` float32 with int32 labels."""
     rng = np.random.default_rng(seed)
     s = image_size

@@ -3,18 +3,18 @@
 Written from the paper (Alg. 1 and 2, Eq. 1 and 6-7) and the simulator's
 stated semantics, importing nothing of the program:
 
-* VGG (conv-BN-ReLU, 2x2 max pools, global mean pool, fc) and basic-block
-  ResNet (3x3 convs, 1x1 projection shortcuts where the width changes),
-  BatchNorm on batch statistics;
+* the model, its loss and its units come from the configuration's family
+  (``bench/models/<kind>.py``: ``init_params``, ``forward``, ``loss``,
+  ``hits``, ``units``, ``unit_scale``);
 * each worker trains its masked sub-model for one local epoch of minibatch
-  SGD with momentum 0.9 restarted every round, cross-entropy plus the
+  SGD with momentum 0.9 restarted every round, the family's loss plus the
   group-lasso term ``lam * sum_g sqrt(|g|) ||theta_g||`` over prunable units;
 * the server averages the masked worker models (``1/W`` each), freezes the
-  CIG order from ``|BN gamma|`` of the global model at the first learning
-  event, learns each worker's pruned rate from the channel model (Alg. 2,
-  Newton inverse interpolation), and the worker prunes that share of its
-  parameters below a global importance threshold at the end of its next
-  local epoch;
+  CIG order from the family's per-unit scale (``|BN gamma|`` for the CNNs)
+  of the global model at the first learning event, learns each worker's
+  pruned rate from the channel model (Alg. 2, Newton inverse
+  interpolation), and the worker prunes that share of its parameters below
+  a global importance threshold at the end of its next local epoch;
 * the global model is evaluated on the test set after every round, in
   batches of 256.
 
@@ -34,85 +34,10 @@ import numpy as np
 from jax import lax
 
 from bench.data import partition_noniid
-from bench.flops import convs_of, forward_flops, head_width, prunable
+from bench.flops import forward_flops
+from bench.models import family
 
 HIGHEST = lax.Precision.HIGHEST
-
-
-# ---------------------------------------------------------------------------
-# model
-# ---------------------------------------------------------------------------
-
-def init_params(model: dict, seed: int) -> Dict[str, jnp.ndarray]:
-    """He-normal (truncated at 2 sigma) convs, unit BN, fc at 1/sqrt(fan_in);
-    one split key per layer, in network order."""
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 256))
-    p: Dict[str, jnp.ndarray] = {}
-    for c in convs_of(model):
-        shape = (c.k, c.k, c.cin, c.cout)
-        p[f"{c.name}/w"] = (
-            jax.random.truncated_normal(next(keys), -2, 2, shape, jnp.float32)
-            * np.sqrt(2.0 / (c.k * c.k * c.cin))
-        )
-        p[f"{c.name}/bn_g"] = jnp.ones((c.cout,))
-        p[f"{c.name}/bn_b"] = jnp.zeros((c.cout,))
-    cin = head_width(model)
-    p["fc/w"] = (
-        jax.random.truncated_normal(next(keys), -2, 2, (cin, model["num_classes"]), jnp.float32)
-        * np.sqrt(1.0 / cin)
-    )
-    p["fc/b"] = jnp.zeros((model["num_classes"],))
-    return p
-
-
-def conv(x, w, stride, precision=HIGHEST):
-    """``SAME`` convolution, NHWC x HWIO, as one matmul over the k*k shifted
-    (and strided) views of the zero-padded input."""
-    k, _, cin, cout = w.shape
-    b, hh, ww, _ = x.shape
-    out = -(-hh // stride)
-    pad = max((out - 1) * stride + k - hh, 0)
-    lo = pad // 2
-    xp = jnp.pad(x, ((0, 0), (lo, pad - lo), (lo, pad - lo), (0, 0)))
-    taps = [xp[:, i:i + (out - 1) * stride + 1:stride, j:j + (out - 1) * stride + 1:stride, :]
-            for i in range(k) for j in range(k)]
-    cols = jnp.concatenate(taps, axis=-1).reshape(b * out * out, k * k * cin)
-    y = jnp.dot(cols, w.reshape(k * k * cin, cout), precision=precision)
-    return y.reshape(b, out, out, cout)
-
-
-def forward(p, model: dict, x, precision=HIGHEST):
-    """Logits ``[b, classes]``; BN normalises over the batch and space."""
-
-    def cbr(name, h, stride=1, relu=True):
-        h = conv(h, p[f"{name}/w"], stride, precision)
-        mu = h.mean(axis=(0, 1, 2))
-        var = h.var(axis=(0, 1, 2))
-        h = (h - mu) * lax.rsqrt(var + 1e-5) * p[f"{name}/bn_g"] + p[f"{name}/bn_b"]
-        return jnp.maximum(h, 0) if relu else h
-
-    if model["kind"] == "vgg":
-        i = 0
-        for e in model["plan"]:
-            if e == "M":
-                x = lax.reduce_window(x, -jnp.inf, lax.max, (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
-            else:
-                x = cbr(f"conv{i}", x)
-                i += 1
-    else:
-        x = cbr("stem", x)
-        for si, (nblocks, _) in enumerate(model["stages"]):
-            for bi in range(nblocks):
-                pre = f"s{si}b{bi}"
-                stride = 2 if (bi == 0 and si > 0) else 1
-                h = cbr(f"{pre}/c2", cbr(f"{pre}/c1", x, stride), relu=False)
-                if f"{pre}/sc/w" in p:
-                    x = cbr(f"{pre}/sc", x, stride, relu=False)
-                elif stride != 1:
-                    x = x[:, ::stride, ::stride, :]
-                x = jnp.maximum(x + h, 0)
-    x = x.mean(axis=(1, 2))
-    return jnp.dot(x, p["fc/w"], precision=precision) + p["fc/b"]
 
 
 # ---------------------------------------------------------------------------
@@ -121,30 +46,19 @@ def forward(p, model: dict, x, precision=HIGHEST):
 
 class Units:
     """Unit layers, their per-unit parameter cost at base shapes, and the
-    arrays (with axis) each unit owns."""
+    arrays (with axis) each unit owns (the family's ``units``)."""
 
-    def __init__(self, model: dict, shapes: Dict[str, tuple]):
-        self.model = model
+    def __init__(self, space: Sequence[tuple], shapes: Dict[str, tuple]):
         self.shapes = shapes
         self.layers: List[str] = []
         self.num: Dict[str, int] = {}
         self.cost: Dict[str, int] = {}
         self.owns: Dict[str, List[Tuple[str, int]]] = {}
-        for name, consumer in prunable(model):
-            kh, kw, cin, cout = shapes[f"{name}/w"]
-            cost = kh * kw * cin + 2
-            if consumer == "fc":
-                cost += shapes["fc/w"][1]
-                cons = ("fc/w", 0)
-            else:
-                nw = shapes[f"{consumer}/w"]
-                cost += nw[0] * nw[1] * nw[3]
-                cons = (f"{consumer}/w", 2)
+        for name, count, owns in space:
             self.layers.append(name)
-            self.num[name] = cout
-            self.cost[name] = int(cost)
-            self.owns[name] = [(f"{name}/w", 3), (f"{name}/bn_g", 0),
-                               (f"{name}/bn_b", 0), cons]
+            self.num[name] = int(count)
+            self.owns[name] = list(owns)
+            self.cost[name] = sum(int(np.prod(shapes[p])) // shapes[p][ax] for p, ax in owns)
         self.total = sum(int(np.prod(s)) for s in shapes.values())
         self.fixed = self.total - sum(self.num[l] * self.cost[l] for l in self.layers)
 
@@ -303,9 +217,10 @@ class Outcome:
     retentions: List[float]                            # final, per worker
     prune_events: Dict[Tuple[int, int], Dict[str, np.ndarray]]
     first_grad_norms: Optional[Dict[str, float]] = None
+    unit_counts: Optional[Dict[str, int]] = None        # prunable layer -> units
 
 
-def _local_train_fn(model, lam, lr, layers, owns, dtype, precision, fault):
+def _local_train_fn(fam, model, lam, lr, layers, owns, dtype, precision, fault):
     """One worker's local phase: minibatch SGD with momentum from zero over
     its plan, on the masked parameters; invalid (padding) steps leave the
     state as it was."""
@@ -324,8 +239,7 @@ def _local_train_fn(model, lam, lr, layers, owns, dtype, precision, fault):
         qm = jax.tree.map(lambda a, m: a * m, q, mask)
         if fault == "half_batch":
             xb, yb = xb[: xb.shape[0] // 2], yb[: yb.shape[0] // 2]
-        logp = jax.nn.log_softmax(forward(qm, model, xb, precision))
-        ce = -jnp.take_along_axis(logp, yb[:, None], axis=1).mean()
+        ce = fam.loss(fam.forward(qm, model, xb, precision), yb)
         return ce + penalty(qm, gl) if lam > 0 else ce
 
     def one(p, mask, gl, x, y, plan, valid):
@@ -358,6 +272,7 @@ def simulate(cfg: dict, wl: dict, task, seed: int, dtype=jnp.float32,
     channel model, Alg. 2 and the index-order prunes), which needs no model
     where the importance order does not read it."""
     model = cfg["model"]
+    fam = family(model["kind"])
     W, batch, E = cfg["num_workers"], cfg["batch_size"], cfg["local_epochs"]
     lr, lam, PI = cfg["lr"], cfg["lam"], cfg["prune_interval"]
     T = cfg["rounds"]
@@ -366,9 +281,9 @@ def simulate(cfg: dict, wl: dict, task, seed: int, dtype=jnp.float32,
     adapt = wl["method"] == "adaptcl"
     importance = wl.get("importance", "cig_bnscalor")
 
-    p0 = init_params(model, seed)
+    p0 = fam.init_params(model, seed)
     shapes = {k: tuple(v.shape) for k, v in p0.items()}
-    units = Units(model, shapes)
+    units = Units(fam.units(model), shapes)
     layers = units.layers
     shards = partition_noniid(task.y_train, W, cfg["noniid_s"], seed)
     n_w = [len(s) for s in shards]
@@ -379,17 +294,18 @@ def simulate(cfg: dict, wl: dict, task, seed: int, dtype=jnp.float32,
     full_flops = forward_flops(model)
     bws = bandwidths(W, wl.get("sigma", 2.0), full_bytes, 1.0)
     rng = np.random.default_rng(seed + 17)
-    train, grad0 = _local_train_fn(model, lam, lr, layers, units.owns, dtype, precision, fault)
+    train, grad0 = _local_train_fn(fam, model, lam, lr, layers, units.owns, dtype, precision,
+                                   fault)
 
     test_x = jnp.asarray(task.x_test, dtype)
-    fwd = jax.jit(lambda q, xb: forward(q, model, xb, precision))
+    fwd = jax.jit(lambda q, xb: fam.forward(q, model, xb, precision))
 
     def accuracy(g):
         gq = {k: v.astype(dtype) for k, v in g.items()}
         hits = 0
         for i in range(0, len(task.y_test), 256):
             lg = np.asarray(fwd(gq, test_x[i:i + 256]).astype(jnp.float32))
-            hits += int((lg.argmax(-1) == task.y_test[i:i + 256]).sum())
+            hits += fam.hits(lg, task.y_test[i:i + 256])
         return hits / len(task.y_test)
 
     def phi(w, index, jitter=True):
@@ -420,7 +336,7 @@ def simulate(cfg: dict, wl: dict, task, seed: int, dtype=jnp.float32,
         if not (adapt and t % PI == 0):
             return
         if cig is None and importance == "cig_bnscalor":
-            cig = {l: np.abs(np.asarray(glob[f"{l}/bn_g"], np.float64)) for l in layers}
+            cig = {l: np.abs(np.asarray(fam.unit_scale(glob, l), np.float64)) for l in layers}
         gam = [units.retention(indices[w]) for w in range(W)]
         phis = [float(np.mean(interval[w])) if interval[w]
                 else phi(w, indices[w], jitter=False) for w in range(W)]
@@ -431,9 +347,9 @@ def simulate(cfg: dict, wl: dict, task, seed: int, dtype=jnp.float32,
         interval = [[] for _ in range(W)]
 
     def scores():
-        """Unit importance: CIG-BNscalor's |gamma| of the global model frozen
-        at the first learning event, or the index order (higher unit ids
-        pruned first)."""
+        """Unit importance: CIG-BNscalor's magnitude of the family's
+        per-unit scale in the global model, frozen at the first learning
+        event, or the index order (higher unit ids pruned first)."""
         if importance == "index":
             return {l: -np.arange(units.num[l], dtype=np.float64) for l in layers}
         return cig
@@ -512,5 +428,5 @@ def simulate(cfg: dict, wl: dict, task, seed: int, dtype=jnp.float32,
     return Outcome(
         globals=globs, accs=accs,
         retentions=[units.retention(i) for i in indices],
-        prune_events=prune_events, first_grad_norms=first_grads,
+        prune_events=prune_events, first_grad_norms=first_grads, unit_counts=dict(units.num),
     )

@@ -105,14 +105,10 @@ def sim_settings(wl: dict, cfg: dict) -> dict:
 def build_sim(wl: dict, cfg: dict, task, seed: int):
     from repro.core.simulation import SimConfig
     from repro.core.timing import HeterogeneityConfig
-    from repro.models.cnn import resnet_config, vgg_config
+
+    from bench.models import family
 
     m = cfg["model"]
-    if m["kind"] == "vgg":
-        cnn = vgg_config(cfg["name"], m["plan"], m["num_classes"], m["image_size"])
-    else:
-        cnn = resnet_config(cfg["name"], m["stem"], [tuple(s) for s in m["stages"]],
-                            m["num_classes"], m["image_size"], bottleneck=False)
     s = dict(wl["sim"])
     sigma = s.pop("sigma", 2.0)
     W = cfg["num_workers"]
@@ -121,19 +117,19 @@ def build_sim(wl: dict, cfg: dict, task, seed: int):
         local_epochs=cfg["local_epochs"], batch_size=cfg["batch_size"], lr=cfg["lr"],
         lam=cfg["lam"], noniid_s=cfg["noniid_s"], importance=cfg["importance"],
         het=HeterogeneityConfig(num_workers=W, sigma=sigma), engine=wl["engine"],
-        cnn=cnn, task=task, seed=seed, **s,
+        task=task, seed=seed, **family(m["kind"]).program_model(cfg["name"], m), **s,
     )
 
 
 def make_inputs(cfg: dict, seed: int):
     """The cell's task from ``--seed``, the program's seed and the shard sizes."""
-    from bench.data import make_task, partition_noniid
+    from bench.data import partition_noniid
+    from bench.models import family
 
     pseed = program_seed(seed)
     m = cfg["model"]
     W = cfg["num_workers"]
-    task = make_task(m["num_classes"], m["image_size"], W * cfg["train_per_worker"],
-                     cfg["test_size"], seed)
+    task = family(m["kind"]).make_task(m, W * cfg["train_per_worker"], cfg["test_size"], seed)
     shard_sizes = [len(s) for s in partition_noniid(task.y_train, W, cfg["noniid_s"], pseed)]
     return task, pseed, shard_sizes
 
@@ -186,16 +182,27 @@ class Run:
         return self.cfg["rounds"]
 
 
+def builds(trainer, sig) -> bool:
+    """Whether ``trainer._call_cached(sig, ...)`` builds its program: a
+    signature new to the trainer that the process's table of built programs
+    does not hold (the program counts ``program_cache.miss``).  A program
+    found in the table is called warm, and its first call there is none."""
+    from repro.core import worker
+
+    return sig not in trainer._step_cache and (trainer._program_key, sig) not in worker._programs
+
+
 def _annotate_program(jax) -> None:
     """Host spans around the program's calls, for labelling idle gaps: each
-    jitted dispatch by its signature's first field, and each evaluation."""
+    jitted dispatch by its signature's first field, ``first_call`` where it
+    builds the program, and each evaluation."""
     from repro.core import simulation, worker
 
     call = worker.LocalTrainer._call_cached
 
     def traced_call(self, sig, build, *args, **kw):
         name = sig[0] if isinstance(sig, tuple) and sig and isinstance(sig[0], str) else "call"
-        first = sig not in self._step_cache
+        first = builds(self, sig)
         with jax.profiler.TraceAnnotation(f"bench.{'first_call' if first else 'call'}.{name}"):
             return call(self, sig, build, *args, **kw)
 

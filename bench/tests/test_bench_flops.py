@@ -3,6 +3,7 @@ import numpy as np
 import pytest
 
 from bench import flops
+from bench.models import family
 
 VGG = {"kind": "vgg", "plan": [8, 8, "M", 16, "M", 24, "M"], "num_classes": 10, "image_size": 16}
 RES = {"kind": "resnet", "stem": 8, "stages": [[2, 8], [2, 16], [1, 32]], "num_classes": 10,
@@ -10,12 +11,7 @@ RES = {"kind": "resnet", "stem": 8, "stages": [[2, 8], [2, 16], [1, 32]], "num_c
 
 
 def _program_cfg(model):
-    from repro.models.cnn import resnet_config, vgg_config
-
-    if model["kind"] == "vgg":
-        return vgg_config("t", model["plan"], model["num_classes"], model["image_size"])
-    return resnet_config("t", model["stem"], [tuple(s) for s in model["stages"]],
-                         model["num_classes"], model["image_size"], bottleneck=False)
+    return family(model["kind"]).program_model("t", model)["cnn"]
 
 
 @pytest.mark.parametrize("model", [VGG, RES], ids=["vgg", "resnet"])
@@ -29,7 +25,13 @@ def test_forward_flops_match_program_at_random_widths(model):
     params = init_cnn(jax.random.PRNGKey(0), cfg)
     space, unit_map = build_unit_space(cfg, params)
     base = {k: v.shape for k, v in params.items()}
-    assert [l.name for l in space.layers] == [n for n, _ in flops.prunable(model)]
+    units = family(model["kind"]).units(model)
+    assert [(l.name, l.num_units) for l in space.layers] == [(n, c) for n, c, _ in units]
+    owned = {}
+    for name, _, owns in units:
+        for leaf, axis in owns:
+            owned.setdefault(leaf, []).append((name, axis))
+    assert {k: sorted(v) for k, v in owned.items()} == {k: sorted(v) for k, v in unit_map.items()}
     rng = np.random.default_rng(0)
     for _ in range(5):
         index = {l.name: np.sort(rng.choice(l.num_units, rng.integers(2, l.num_units + 1),

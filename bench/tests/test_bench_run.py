@@ -12,27 +12,17 @@ from pathlib import Path
 
 import pytest
 
+from bench.tests.cells import tiny
+
 ROOT = Path(__file__).resolve().parents[2]
 SEED = 2 ** 31 + 977
 
 
-def _tiny(cell, per_worker=64):
-    from bench import run
-
-    wl, cfg = run.load_cell(cell)
-    cfg = dict(cfg, num_workers=4, train_per_worker=per_worker, test_size=300, rounds=6)
-    if cfg["model"]["kind"] == "vgg":
-        cfg["model"] = dict(cfg["model"], plan=[8, 8, "M", 16, "M", 16, "M"], image_size=8)
-    else:
-        cfg["model"] = dict(cfg["model"], stem=8, stages=[[2, 8], [1, 16]], image_size=8)
-    return wl, cfg
-
-
 def _measure(cell, per_worker=64, **kw):
-    """A run at 4 workers of ``per_worker`` images, 6 rounds, 8 px."""
+    """A run of the tiny cell (``cells.tiny``)."""
     from bench import run
 
-    wl, cfg = _tiny(cell, per_worker)
+    wl, cfg = tiny(cell, per_worker)
     return run.measure(wl, cfg, SEED, 0.1, False, require_chip=False, **kw)
 
 
@@ -105,7 +95,7 @@ def test_half_batch_left_out_is_not_correct(monkeypatch):
 def test_bf16_control_fails_a_limit():
     from bench import readings, run
 
-    wl, cfg = _tiny("vgg16-w10-sync", 1024)
+    wl, cfg = tiny("vgg16-w10-sync", 1024)
     run.start_jax(1, require_chip=False)
     got = readings.read_seed(wl, cfg, SEED, controls=["bf16"])
     limits = {k: lim for k, lim in wl["limits"].items() if k != "repeat_gap"}

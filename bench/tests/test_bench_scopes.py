@@ -1,12 +1,14 @@
 """Device time per scope and idle time per span (``bench/scopes.py``), the
 breakdown of a traced simulation (``bench/breakdown.py``) and the compile
 readers, on traces built here and on real CPU traces at a tiny size."""
+import collections
 from types import SimpleNamespace
 
 import pytest
 
 from bench import scopes
 from bench import trace as tr
+from bench.tests.cells import tiny
 
 
 def _op(name, start, dur, op_name="", module="jit_chunk", plane="/device:TPU:0"):
@@ -148,18 +150,6 @@ def test_op_names_and_scopes_of_a_real_cpu_trace(tmp_path):
     assert tr.summarize(events).busy_s > 0          # the harness's reduction reads them too
 
 
-def _tiny(cell):
-    from bench import run
-
-    wl, cfg = run.load_cell(cell)
-    cfg = dict(cfg, num_workers=4, train_per_worker=64, test_size=300, rounds=6)
-    if cfg["model"]["kind"] == "vgg":
-        cfg["model"] = dict(cfg["model"], plan=[8, 8, "M", 16, "M", 16, "M"], image_size=8)
-    else:
-        cfg["model"] = dict(cfg["model"], stem=8, stages=[[2, 8], [1, 16]], image_size=8)
-    return wl, cfg
-
-
 @pytest.fixture
 def restore_program(monkeypatch):
     """The traced paths wrap two functions of the program; put them back."""
@@ -169,10 +159,27 @@ def restore_program(monkeypatch):
     monkeypatch.setattr(simulation, "_env_accuracy", simulation._env_accuracy)
 
 
-def test_breakdown_of_a_tiny_traced_simulation(tmp_path, restore_program):
+@pytest.fixture
+def cleared_before_trace(monkeypatch):
+    """Empty the process's table of built programs just before the traced
+    simulation (where the harness wraps the program for the trace), so that
+    the simulation read builds, traces and loads every program again."""
+    from bench import run
+    from repro.core import worker
+
+    annotate = run._annotate_program
+
+    def cleared(jax):
+        worker.clear_program_cache()
+        annotate(jax)
+
+    monkeypatch.setattr(run, "_annotate_program", cleared)
+
+
+def test_breakdown_of_a_tiny_traced_simulation(tmp_path, restore_program, cleared_before_trace):
     from bench import breakdown
 
-    wl, cfg = _tiny("resnet20-w10-sync")
+    wl, cfg = tiny("resnet20-w10-sync")
     out = breakdown.profile_cell(wl, cfg, 2 ** 31 + 977, tmp_path / "trace", require_chip=False)
     scoped = {k: v for k, v in out["scope_s"].items() if k != "unscoped"}
     assert {"broadcast", "train.step", "aggregate", "prune"} <= set(scoped)
@@ -184,17 +191,31 @@ def test_breakdown_of_a_tiny_traced_simulation(tmp_path, restore_program):
     m = out["metrics"]
     assert m["local_train_ms_per_round"] > 0 and m["prune_ms_per_round"] > 0
     assert m["trace_lower_s_per_sim"] > 0 and m["idle_eval_ms_per_round"] > 0
+    assert m["cache_load_s_per_sim"] > 0
     assert out["span_n"]["chunk.dispatch"] == 3
 
 
-def test_traced_run_reports_the_compile_counters(restore_program):
+def test_traced_run_reports_the_compile_counters(restore_program, cleared_before_trace):
     from bench import run
 
-    wl, cfg = _tiny("vgg16-w10-sync")
+    wl, cfg = tiny("vgg16-w10-sync")
     out = run.measure(wl, cfg, 2 ** 31 + 977, 0.1, True, require_chip=False)
     assert out["correct"], out
     assert out["metrics"]["trace_lower_s_per_sim"]["value"] > 0
     assert out["metrics"]["cache_load_s_per_sim"]["value"] > 0
+
+
+def test_a_call_builds_only_where_the_program_table_misses(monkeypatch):
+    from bench import run
+    from repro.core import worker
+
+    trainer = SimpleNamespace(_step_cache={}, _program_key=("settings",))
+    table = collections.OrderedDict({(("settings",), ("fused", 1)): object()})
+    monkeypatch.setattr(worker, "_programs", table)
+    assert not run.builds(trainer, ("fused", 1))     # warm from an earlier simulation
+    assert run.builds(trainer, ("fused", 2))         # a miss: built here
+    trainer._step_cache[("fused", 2)] = [object(), True]
+    assert not run.builds(trainer, ("fused", 2))     # called before by this trainer
 
 
 @pytest.mark.parametrize("metric", ["trace_lower_s_per_sim", "cache_load_s_per_sim"])
