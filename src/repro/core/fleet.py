@@ -116,10 +116,15 @@ def masks_from_presence(
     flat: UnitFlat,
     unit_map: UnitMap,
     base_shapes: Mapping[str, tuple],
+    broadcast: bool = False,
 ) -> Dict[str, jnp.ndarray]:
     """Device rebuild of the ``[W, ...]`` 0/1 mask stacks from a flat
     presence matrix — the in-scan analogue of ``FleetEngine.refresh_masks``
-    (same product-over-governed-axes construction, pure ``jnp``)."""
+    (same product-over-governed-axes construction, pure ``jnp``).
+
+    ``broadcast`` keeps each mask at size 1 along every axis no unit layer
+    governs (``[W, 1, ..., n_units, ..., 1]``), which multiplies into the
+    parameters as the full mask does, at the cost of the units' count."""
     W = presence.shape[0]
     rows = {
         name: presence[:, flat.offsets[l] : flat.offsets[l] + flat.sizes[l]]
@@ -127,7 +132,8 @@ def masks_from_presence(
     }
     masks: Dict[str, jnp.ndarray] = {}
     for path, shape in base_shapes.items():
-        m = jnp.ones((W,) + tuple(shape), jnp.float32)
+        m = jnp.ones((W,) + ((1,) * len(shape) if broadcast else tuple(shape)),
+                     jnp.float32)
         for lname, axis in unit_map.get(path, ()):
             bshape = [W] + [1] * len(shape)
             bshape[1 + axis] = shape[axis]
@@ -455,8 +461,12 @@ class FleetEngine:
             ))
             for k, v in base_params.items()
         }
+        # a model with per-unit (broadcast) masks starts with masks of ones
+        # at size 1 on every axis: no parameter-sized stack of ones
+        bcast = self.trainer.cfg.fleet_layout == "lean"
         masks = {
-            k: put(np.ones((W,) + tuple(v.shape), np.float32))
+            k: put(np.ones((W,) + ((1,) * v.ndim if bcast else tuple(v.shape)),
+                           np.float32))
             for k, v in base_params.items()
         }
         state = FleetState(

@@ -100,7 +100,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.models.cnn import cnn_flops_from_shapes, extract_bn_scales
+from repro.models.interface import worker_map
 from repro.sharding.specs import fleet_sharding
 
 from repro.optim.group_lasso import group_size_sqrt_from_shapes
@@ -161,6 +161,16 @@ def validate_fused_config(sim) -> None:
         raise ValueError(
             "engine='fused' supports compute='dense' only — the block_skip "
             "interpret-mode kernel inside lax.scan is out of scope off-TPU"
+        )
+    faults = sim.scenario.faults if sim.scenario is not None else None
+    if sim.net.fleet_layout == "lean" and (
+            sim.dgc_sparsity > 0.0 or sim.robust is not None
+            or (faults is not None
+                and (faults.byzantine is not None or faults.channel is not None))):
+        raise ValueError(
+            f"model {sim.net.name!r} keeps its masks as per-unit vectors (lean "
+            "layout); DGC and the robust submission path read masks at the "
+            "parameters' shapes"
         )
     supported = STATIC_METHODS | DEVICE_METHODS
     if sim.importance not in supported:
@@ -241,10 +251,12 @@ def _build_chunk_fn(trainer, unit_map, base_shapes, flat: UnitFlat, lam,
     cross-worker collective touches a score, so sharding the row axis
     cannot reorder a removal walk."""
     train_one = trainer.make_resident_train(unit_map, lam, carry_momentum=True)
-    vm_train = jax.vmap(
+    vm_train = worker_map(
+        trainer.cfg,
         lambda p, m0, x, y, plan, valid, mask, gl:
             train_one(p, x, y, plan, valid, mask, gl, m0)
     )
+    lean = trainer.cfg.fleet_layout == "lean"
     slices = {
         name: (int(flat.offsets[l]), int(flat.sizes[l]))
         for l, name in enumerate(flat.names)
@@ -324,7 +336,8 @@ def _build_chunk_fn(trainer, unit_map, base_shapes, flat: UnitFlat, lam,
     def chunk(params, momentum, presence, global_p, dgc_res, health, xs, ys,
               sizes, per_round, orders):
         with jax.named_scope("broadcast"):
-            masks = masks_from_presence(presence, flat, unit_map, base_shapes)
+            masks = masks_from_presence(presence, flat, unit_map, base_shapes,
+                                        broadcast=lean)
 
         def body(carry, inp):
             params, masks, presence, global_p, momentum, dgc_res, health = carry
@@ -353,13 +366,13 @@ def _build_chunk_fn(trainer, unit_map, base_shapes, flat: UnitFlat, lam,
                 )
                 m0 = (momentum if resident_momentum
                       else jax.tree.map(jnp.zeros_like, params))
-            params, m_out, _ = vm_train(
+            params, m_out, _, cnt = vm_train(
                 params, m0, xs, ys, inp["plan_a"], inp["valid_a"], masks, gl
             )
             momentum = m_out if resident_momentum else momentum
 
             def prune_branch(op):
-                params, masks, presence, momentum = op
+                params, masks, presence, momentum, cnt = op
                 with jax.named_scope("importance"):
                     if importance in STATIC_METHODS:
                         ow = orders
@@ -376,7 +389,8 @@ def _build_chunk_fn(trainer, unit_map, base_shapes, flat: UnitFlat, lam,
                         )(scores)
                 with jax.named_scope("prune"):
                     pres2 = prune_presence_rows(presence, ow, inp["budgets"], flat)
-                    masks2 = masks_from_presence(pres2, flat, unit_map, base_shapes)
+                    masks2 = masks_from_presence(pres2, flat, unit_map, base_shapes,
+                                                 broadcast=lean)
                     params2 = {k: params[k] * masks2[k] for k in params}
                     mom2 = (
                         {k: momentum[k] * masks2[k] for k in momentum}
@@ -389,16 +403,17 @@ def _build_chunk_fn(trainer, unit_map, base_shapes, flat: UnitFlat, lam,
                         )
                         m0b = (mom2 if resident_momentum
                                else jax.tree.map(jnp.zeros_like, params2))
-                        params2, m_b, _ = vm_train(
+                        params2, m_b, _, cnt_b = vm_train(
                             params2, m0b, xs, ys,
                             inp["plan_b"], inp["valid_b"], masks2, gl2,
                         )
+                        cnt = {k: cnt[k] + cnt_b[k] for k in cnt}
                     mom2 = m_b if resident_momentum else mom2
-                return params2, masks2, pres2, mom2
+                return params2, masks2, pres2, mom2, cnt
 
-            params, masks, presence, momentum = jax.lax.cond(
+            params, masks, presence, momentum, cnt = jax.lax.cond(
                 inp["prune_any"], prune_branch, lambda op: op,
-                (params, masks, presence, momentum),
+                (params, masks, presence, momentum, cnt),
             )
 
             # submission boundary: DGC top-|.| delta compression on device.
@@ -469,17 +484,19 @@ def _build_chunk_fn(trainer, unit_map, base_shapes, flat: UnitFlat, lam,
                 }
             return (
                 params, masks, presence, global_p, momentum, dgc_res, health
-            ), (presence, global_p, kept_w, total_w, quar_row)
+            ), (presence, global_p, kept_w, total_w, quar_row, cnt)
 
         carry0 = (params, masks, presence, global_p, momentum, dgc_res, health)
         (params, masks, presence, global_p, momentum, dgc_res, health), (
-            pres_seq, glob_seq, kept_seq, total_seq, quar_seq
+            pres_seq, glob_seq, kept_seq, total_seq, quar_seq, cnt_seq
         ) = jax.lax.scan(body, carry0, per_round)
         return (params, momentum, presence, global_p, dgc_res, health,
-                pres_seq, glob_seq, kept_seq, total_seq, quar_seq)
+                pres_seq, glob_seq, kept_seq, total_seq, quar_seq, cnt_seq)
 
     if mesh is None:
-        return jax.jit(chunk)
+        # the lean layout hands the parameter stacks and the global model
+        # over to the program: their outputs take the inputs' buffers
+        return jax.jit(chunk, donate_argnums=(0, 3) if lean else ())
 
     # one lax.scan program PER SHARD: row-stacked args shard over the fleet
     # axis (dim 0 for state, dim 1 for [K, W, ...] per-round tensors), the
@@ -516,7 +533,7 @@ def _build_chunk_fn(trainer, unit_map, base_shapes, flat: UnitFlat, lam,
         in_specs=(fleet, fleet, fleet, rep, fleet, rep, fleet, fleet, fleet,
                   per_round_specs, fleet),
         out_specs=(fleet, fleet, fleet, rep, fleet, rep, P(None, fleet_axis),
-                   rep, kt, kt, rep),
+                   rep, kt, kt, rep, kt),
         check_vma=False,
     ))
 
@@ -631,6 +648,7 @@ def run_sync_fused(sim, env):
         prune_round_count = 0
         prune_events = []
         fused_chunks = 0
+        counters: Dict[str, float] = {}
 
         clock = 0.0
         comm_bytes = 0.0
@@ -651,7 +669,7 @@ def run_sync_fused(sim, env):
                 shapes = subparam_shapes(idx, unit_map, base_shapes)
                 ent = (
                     sum(int(np.prod(s)) * 4 for s in shapes.values()),
-                    cnn_flops_from_shapes(shapes, sim.cnn),
+                    env.model.flops_from_shapes(shapes),
                 )
                 _count_cache[key] = ent
             return ent
@@ -918,7 +936,7 @@ def run_sync_fused(sim, env):
         # ---- ONE device dispatch for the whole chunk ---------------------
         with span("chunk.dispatch"):
             (state.params, mom_out, _, global_dev, dgc_res_dev, health_dev,
-             pres_seq, glob_seq, kept_seq, total_seq, quar_seq) = (
+             pres_seq, glob_seq, kept_seq, total_seq, quar_seq, cnt_seq) = (
                 trainer._call_cached(
                     sig, build,
                     state.params, momentum_arg, presence_dev, global_dev,
@@ -940,6 +958,8 @@ def run_sync_fused(sim, env):
                 total_np = np.asarray(total_seq)
             if quar_cfg is not None:                               # [K, W] 0/1
                 quar_np = np.asarray(quar_seq)
+            for k, v in cnt_seq.items():      # model counters, [K, W]
+                counters[k] = counters.get(k, 0.0) + float(np.asarray(v, np.float64).sum())
 
         # ---- post-chunk host accounting (payloads, clock, ledger); the
         # rounds' evaluations follow it, in round order ------------------
@@ -1041,7 +1061,7 @@ def run_sync_fused(sim, env):
                 if cig_scores is None and sim.importance == "cig_bnscalor":
                     cig_scores = METHODS["cig_bnscalor"](ImportanceContext(
                         unit_counts=env.space.unit_counts,
-                        scales=extract_bn_scales(global_params, sim.cnn),
+                        scales=env.model.unit_scales(global_params),
                     ))
                 if drift_now:
                     histories[sim.scenario.faults.drift.worker].invalidate()
@@ -1082,7 +1102,7 @@ def run_sync_fused(sim, env):
         scenario_rounds=scen_rows,
         flops_per_image_final=float(np.mean([c[0] for c in final_costs])),
         blocks_per_image_final=float(np.mean([c[2] for c in final_costs])),
-        prune_events=prune_events, fused_chunks=fused_chunks,
+        prune_events=prune_events, fused_chunks=fused_chunks, counters=counters,
         fault_ledger={
             **fault_ledger(plan_all.events),
             "quarantined_commits": quarantined_commits,
@@ -1133,7 +1153,8 @@ def _build_async_chunk_fn(trainer, unit_map, base_shapes, lam, *, method, W,
     staleness integers (the host verifies both against the plan) plus the
     post-commit globals captured at eval events."""
     train_one = trainer.make_resident_train(unit_map, lam)
-    vm_train = jax.vmap(
+    vm_train = worker_map(
+        trainer.cfg,
         lambda p, x, y, plan, valid, mask, gl:
             train_one(p, x, y, plan, valid, mask, gl)
     )
@@ -1229,7 +1250,7 @@ def _build_async_chunk_fn(trainer, unit_map, base_shapes, lam, *, method, W,
             p0 = {k: jnp.take(v, rows, axis=0) for k, v in fetched.items()}
             xb = jnp.take(xs, rows, axis=0)
             yb = jnp.take(ys, rows, axis=0)
-            trained, _, _ = vm_train(p0, xb, yb, plans, pvalid, masks, gl)
+            trained, _, _, _ = vm_train(p0, xb, yb, plans, pvalid, masks, gl)
             eval_buf = {
                 k: jnp.zeros((EB,) + tuple(base_shapes[k]), jnp.float32)
                 for k in g

@@ -111,17 +111,7 @@ from repro.data.synthetic import (
     partition_dirichlet,
     partition_noniid,
 )
-from repro.models.cnn import (
-    CNNConfig,
-    build_unit_space,
-    cnn_apply,
-    cnn_block_compute,
-    cnn_flops,
-    cnn_flops_from_shapes,
-    extract_bn_scales,
-    init_cnn,
-    vgg_config,
-)
+from repro.models.cnn import CNNConfig, vgg_config
 
 from .aggregation import (
     AsyncServer,
@@ -287,9 +277,18 @@ class SimConfig:
     mesh: Optional[object] = None
     fleet_axis: str = "fleet"
     cnn: CNNConfig = dataclasses.field(default_factory=default_cnn)
+    # a model of another family (``models.interface``), in ``cnn``'s place
+    # where set, e.g. ``models.deepseek_v3.DeepSeekV3Config``
+    model: Optional[object] = None
     task: Optional[SyntheticImageTask] = None
     eval_every: int = 1
     seed: int = 0
+
+    @property
+    def net(self):
+        """The model the simulation trains: ``model`` where set, else
+        ``cnn``."""
+        return self.model if self.model is not None else self.cnn
 
 
 @dataclasses.dataclass
@@ -387,6 +386,9 @@ class SimResult:
     # events (core.spans), by name; nested spans are inside their parents
     span_s: Dict[str, float] = dataclasses.field(default_factory=dict)
     span_n: Dict[str, int] = dataclasses.field(default_factory=dict)
+    # the model's device counters (``model.counters``), summed over every
+    # worker's valid training steps; the fused sync engine fills it
+    counters: Dict[str, float] = dataclasses.field(default_factory=dict)
 
 
 def _env_accuracy(env: "_Env", params) -> float:
@@ -394,21 +396,22 @@ def _env_accuracy(env: "_Env", params) -> float:
     cache: one compiled program per test-batch shape instead of op-by-op
     dispatch (which paid an untracked trace+compile tax on every run).
     Counted like any other dispatch, so ``host_dispatches`` and
-    ``compile_walltime_s`` stay honest across engines."""
-    cfg = env.sim.cnn
+    ``compile_walltime_s`` stay honest across engines.  The model's
+    ``eval_outputs`` run on the device, its ``hits`` on the host."""
+    model = env.model
     x, y = env.task.x_test, env.task.y_test
     with span("evaluate"):
         jp = {k: jnp.asarray(v) for k, v in params.items()}
         correct = 0
         for i in range(0, len(x), 256):
             xb = x[i : i + 256]
-            logits = env.trainer._call_cached(
+            out = env.trainer._call_cached(
                 ("eval_logits", xb.shape),
-                lambda: jax.jit(lambda p, q: cnn_apply(p, cfg, q)),
+                lambda: jax.jit(lambda p, q: model.eval_outputs(p, q)),
                 jp, jnp.asarray(xb),
                 count_compile=False,
             )
-            correct += int((np.argmax(np.asarray(logits), -1) == y[i : i + 256]).sum())
+            correct += model.hits(out, y[i : i + 256])
         return correct / len(x)
 
 
@@ -477,10 +480,13 @@ class _Env:
                 f"SimConfig.noniid_s={sim.noniid_s} are competing Non-IID "
                 "partitioners — set exactly one"
             )
-        self.task = sim.task or SyntheticImageTask(
-            num_classes=sim.cnn.num_classes, image_size=sim.cnn.image_size,
-            train_size=1280, test_size=512, seed=sim.seed,
-        )
+        self.model = model = sim.net
+        if sim.importance not in model.importances:
+            why = ("it has no per-unit scale" if sim.importance == "cig_bnscalor"
+                   else f"it supports {sorted(model.importances)}")
+            raise ValueError(
+                f"model {model.name!r} cannot prune by {sim.importance!r}: {why}")
+        self.task = sim.task or model.default_task(sim.seed)
         self.shards = (
             partition_dirichlet(
                 self.task.y_train, sim.num_workers, skew, seed=sim.seed
@@ -491,14 +497,14 @@ class _Env:
             )
         )
         key = jax.random.PRNGKey(sim.seed)
-        self.base_params = {k: np.asarray(v) for k, v in init_cnn(key, sim.cnn).items()}
+        self.base_params = {k: np.asarray(v) for k, v in model.init(key).items()}
         self.base_shapes = {k: v.shape for k, v in self.base_params.items()}
-        self.space, self.unit_map = build_unit_space(sim.cnn, self.base_params)
+        self.space, self.unit_map = model.unit_space(self.base_params)
         self.full_bytes = payload_bytes(full_index(self.space), self.space)
-        self.full_flops = cnn_flops(self.base_params, sim.cnn)
+        self.full_flops = model.flops_from_shapes(self.base_shapes)
         self.bandwidths = make_bandwidths(sim.het, self.full_bytes, sim.t_train_full)
         self.trainer = LocalTrainer(
-            sim.cnn, lr=sim.lr,
+            model, lr=sim.lr,
             compute=sim.compute, compute_blocks=sim.compute_blocks,
         )
         self.fleet = FleetEngine(
@@ -521,7 +527,7 @@ class _Env:
         cached = self._acct_cache.get(key)
         if cached is None:
             shapes = subparam_shapes(index, self.unit_map, self.base_shapes)
-            ideal = cnn_flops_from_shapes(shapes, self.sim.cnn)
+            ideal = self.model.flops_from_shapes(shapes)
             if self.sim.compute == "block_skip":
                 masks = {
                     l.name: np.asarray(
@@ -529,7 +535,7 @@ class _Env:
                     )
                     for l in self.space.layers
                 }
-                bc = cnn_block_compute(self.sim.cnn, masks, self.sim.compute_blocks)
+                bc = self.model.block_compute(masks, self.sim.compute_blocks)
                 cached = (bc["flops"], ideal, bc["blocks"])
             elif self.sim.engine in ("masked", "fused"):
                 # dense masked programs run the base shapes regardless of masks
@@ -579,7 +585,7 @@ class _Env:
     ) -> float:
         sim = self.sim
         bytes_raw = sum(int(np.prod(s)) * 4 for s in shapes.values())
-        flops_w = cnn_flops_from_shapes(shapes, sim.cnn)
+        flops_w = self.model.flops_from_shapes(shapes)
         jmult = (
             float(np.exp(self.rng.normal(0, sim.time_jitter)))
             if jitter and sim.time_jitter > 0 else 1.0
@@ -988,7 +994,7 @@ def _run_sync(sim: SimConfig, env: _Env) -> SimResult:
             if cig_scores is None and sim.importance == "cig_bnscalor":
                 cig_scores = METHODS["cig_bnscalor"](ImportanceContext(
                     unit_counts=env.space.unit_counts,
-                    scales=extract_bn_scales(global_params, sim.cnn),
+                    scales=env.model.unit_scales(global_params),
                 ))
             if drift_trigger:
                 histories[sim.scenario.faults.drift.worker].invalidate()
@@ -1754,12 +1760,14 @@ def _finalize(sim, env, acc_time, het_traj, sim_traj, upd_times, retentions,
               global_params=None, host_roundtrips=0,
               scenario_rounds=None, flops_per_image_final=0.0,
               blocks_per_image_final=0.0, prune_events=None,
-              fused_chunks=0, fault_ledger=None, stack_devices=1) -> SimResult:
+              fused_chunks=0, fault_ledger=None, stack_devices=1,
+              counters=None) -> SimResult:
     accs = np.array([a for _, a in acc_time])
     times = np.array([t for t, _ in acc_time])
     best = int(np.argmax(accs))
     param_sizes = [sum(v.size for v in p.values()) for p in worker_params]
-    flops = [cnn_flops(p, sim.cnn) for p in worker_params]
+    flops = [env.model.flops_from_shapes({k: v.shape for k, v in p.items()})
+             for p in worker_params]
     full_size = sum(v.size for v in env.base_params.values())
     if sim.mesh is not None:
         fleet_axis_size = int(sim.mesh.shape[sim.fleet_axis])
@@ -1803,6 +1811,7 @@ def _finalize(sim, env, acc_time, het_traj, sim_traj, upd_times, retentions,
         blocks_executed=env.blocks_executed,
         flops_per_image_final=flops_per_image_final,
         blocks_per_image_final=blocks_per_image_final,
+        counters=dict(counters or {}),
         global_params={k: np.asarray(v) for k, v in global_params.items()}
         if global_params is not None else None,
     )

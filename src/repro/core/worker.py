@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.cnn import CNNConfig, cnn_apply, prunable_layer_names
+from repro.models.interface import worker_map
 from repro.optim.group_lasso import group_lasso_penalty, group_size_sqrt
 from repro.optim.optimizers import apply_updates, momentum
 
@@ -194,7 +194,7 @@ class LocalTrainer:
 
     def __init__(
         self,
-        cnn_cfg: CNNConfig,
+        model,
         lr: float = 0.05,
         beta: float = 0.9,
         compute: str = "dense",
@@ -203,7 +203,11 @@ class LocalTrainer:
     ):
         if compute not in ("dense", "block_skip"):
             raise ValueError(f"unknown compute path {compute!r}")
-        self.cfg = cnn_cfg
+        if compute not in model.compute_paths:
+            raise ValueError(
+                f"model {model.name!r} runs compute paths {model.compute_paths}, "
+                f"not {compute!r}")
+        self.cfg = model
         self.lr = lr
         self.beta = beta
         self.compute = compute
@@ -216,10 +220,9 @@ class LocalTrainer:
         # what every build reads of the trainer: the first half of a key of
         # the process's table of built programs
         self._program_key = (
-            cnn_cfg, float(lr), float(beta), compute, self.compute_blocks,
+            model, float(lr), float(beta), compute, self.compute_blocks,
             self.compute_interpret,
         )
-        self._prunable = prunable_layer_names(cnn_cfg)
         self._step_cache: Dict = {}
         self.compile_count = 0  # reconfigure-induced recompiles (overhead bench)
         self.dispatch_count = 0  # jitted training programs launched (host->device)
@@ -275,31 +278,29 @@ class LocalTrainer:
         return out
 
     def _masked_logits(self, qm, mask, xb):
-        """Logits of the masked base-shape model; the block-skip path reads
-        each prunable layer's unit mask off its ``bn_g`` mask row (the
-        [width] 0/1 vector the fleet's ``refresh_masks`` writes)."""
+        """Outputs of the masked base-shape model; the block-skip path reads
+        each prunable layer's unit mask off the masks
+        (``model.block_unit_masks``)."""
         if self.compute == "block_skip":
-            um = {n: mask[f"{n}/bn_g"] for n in self._prunable}
-            return cnn_apply(
-                qm, self.cfg, xb, compute="block_skip", unit_masks=um,
+            return self.cfg.apply(
+                qm, xb, compute="block_skip", unit_masks=self.cfg.block_unit_masks(mask),
                 blocks=self.compute_blocks, interpret=self.compute_interpret,
             )
-        return cnn_apply(qm, self.cfg, xb)
+        return self.cfg.apply(qm, xb)
 
     def _masked_ce(self, qm, mask, xb, yb):
-        """Mean cross-entropy of the masked model (shared by the masked
-        stacked and resident train closures)."""
-        logp = jax.nn.log_softmax(self._masked_logits(qm, mask, xb))
-        return -jnp.take_along_axis(logp, yb[:, None], axis=1).mean()
+        """Mean training loss of the masked model and the step's counters
+        (``model.counters``), shared by the masked stacked and resident
+        train closures."""
+        out = self._masked_logits(qm, mask, xb)
+        return self.cfg.loss(out, yb), self.cfg.counters(out)
 
     def _make_loss(self, unit_map, lam: float):
         cfg = self.cfg
         frozen_map = {k: tuple(v) for k, v in unit_map.items()}
 
         def loss_fn(p, x, y):
-            logits = cnn_apply(p, cfg, x)
-            logp = jax.nn.log_softmax(logits)
-            ce = -jnp.take_along_axis(logp, y[:, None], axis=1).mean()
+            ce = cfg.loss(cfg.apply(p, x), y)
             if lam > 0.0:
                 ce = ce + group_lasso_penalty(p, frozen_map, lam)
             return ce
@@ -341,9 +342,7 @@ class LocalTrainer:
         frozen_map = {k: tuple(v) for k, v in unit_map.items()}
 
         def ce(p, xb, yb):
-            logits = cnn_apply(p, cfg, xb)
-            logp = jax.nn.log_softmax(logits)
-            return -jnp.take_along_axis(logp, yb[:, None], axis=1).mean()
+            return cfg.loss(cfg.apply(p, xb), yb)
 
         def scan_train(loss_fn, p, x, y, plan):
             opt_state = opt.init(p)
@@ -373,7 +372,7 @@ class LocalTrainer:
             def train_one(p, x, y, plan, mask, gl_size):
                 def loss_fn(q, xb, yb):
                     qm = jax.tree.map(lambda w, m: w * m, q, mask)
-                    l = self._masked_ce(qm, mask, xb, yb)
+                    l = self._masked_ce(qm, mask, xb, yb)[0]
                     if lam > 0.0:
                         l = l + group_lasso_penalty(qm, frozen_map, lam, size_sqrt=gl_size)
                     return l
@@ -470,6 +469,10 @@ class LocalTrainer:
         returned ``train_one`` then takes the incoming momentum stack as an
         extra leading state argument (the cross-round resident-momentum
         mode), instead of ``opt.init``-ing zeros every phase.
+
+        ``train_one`` returns the trained parameters, the optimizer state,
+        the mean loss over valid steps and the model's step counters
+        (``model.counters``) summed over valid steps.
         """
         cfg, opt = self.cfg, momentum(self.lr, self.beta)
         frozen_map = {k: tuple(v) for k, v in unit_map.items()}
@@ -477,10 +480,10 @@ class LocalTrainer:
         def train_one(p, x, y, plan, valid, mask, gl_size, m0=None):
             def loss_fn(q, xb, yb):
                 qm = jax.tree.map(lambda w, m: w * m, q, mask)
-                l = self._masked_ce(qm, mask, xb, yb)
+                l, cnt = self._masked_ce(qm, mask, xb, yb)
                 if lam > 0.0:
                     l = l + group_lasso_penalty(qm, frozen_map, lam, size_sqrt=gl_size)
-                return l
+                return l, cnt
 
             opt_state = m0 if carry_momentum else opt.init(p)
 
@@ -489,22 +492,26 @@ class LocalTrainer:
                 vb = v > 0
                 q, st = carry
                 with jax.named_scope("train.step"):
-                    loss, grads = jax.value_and_grad(loss_fn)(q, x[sel], y[sel])
+                    (loss, cnt), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+                        q, x[sel], y[sel])
                 with jax.named_scope("train.update"):
                     updates, st2 = opt.update(grads, st, q)
                     q2 = apply_updates(q, updates)
                 with jax.named_scope("train.gate"):
                     q = jax.tree.map(lambda a, b: jnp.where(vb, a, b), q2, q)
                     st = jax.tree.map(lambda a, b: jnp.where(vb, a, b), st2, st)
-                    return (q, st), jnp.where(vb, loss, 0.0)
+                    cnt = {k: jnp.where(vb, c, 0) for k, c in cnt.items()}
+                    return (q, st), (jnp.where(vb, loss, 0.0), cnt)
 
             # device scopes: the op names of a profile say which phase of
             # the step an op belongs to (named scopes touch no fusion)
             with jax.named_scope("train"):
-                (p, opt_state), losses = jax.lax.scan(body, (p, opt_state), (plan, valid))
+                (p, opt_state), (losses, cnts) = jax.lax.scan(
+                    body, (p, opt_state), (plan, valid))
                 p = jax.tree.map(lambda w, m: w * m, p, mask)
                 steps = jnp.maximum(valid.sum(), 1.0)
-                return p, opt_state, losses.sum() / steps
+                return (p, opt_state, losses.sum() / steps,
+                        {k: c.sum(axis=0) for k, c in cnts.items()})
 
         return train_one
 
@@ -537,9 +544,12 @@ class LocalTrainer:
             one = self.make_resident_train(unit_map, lam, carry_momentum=carry_m)
             if carry_m:
                 def with_m(p, m0, x, y, plan, valid, mask, gl_size):
-                    return one(p, x, y, plan, valid, mask, gl_size, m0)
-                return jax.jit(jax.vmap(with_m))
-            return jax.jit(jax.vmap(one))
+                    return one(p, x, y, plan, valid, mask, gl_size, m0)[:3]
+                return jax.jit(worker_map(self.cfg, with_m))
+
+            def train_one(*a):
+                return one(*a)[:3]
+            return jax.jit(worker_map(self.cfg, train_one))
 
         if gl_sizes is None:   # base-shape factors for every worker
             W = plans.shape[0]
@@ -616,7 +626,7 @@ def local_unit_stats(
         gw[lname] = acc
     # activation statistic (HRank proxy): real per-filter mean|activation|
     stats: Dict[str, jnp.ndarray] = {}
-    cnn_apply(jparams, trainer.cfg, jnp.asarray(x[:64]), stats=stats)
+    trainer.cfg.apply(jparams, jnp.asarray(x[:64]), stats=stats)
     acts = {
         lname: np.asarray(stats[lname], np.float64) for lname in norms if lname in stats
     }

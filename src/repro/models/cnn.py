@@ -68,6 +68,62 @@ class CNNConfig:
     stages: Tuple[Tuple[int, int], ...] = ()
     bottleneck: bool = True
 
+    # the model interface (``models.interface``): every compute path, every
+    # pruning criterion, workers vmapped with masks at the parameters' shapes
+    compute_paths = ("dense", "block_skip")
+    importances = frozenset({
+        "cig_bnscalor", "index", "no_adjacent", "no_identical", "no_constant",
+        "l1", "taylor", "fpgm", "hrank",
+    })
+    fleet_layout = "stacked"
+
+    def init(self, key) -> Dict[str, jnp.ndarray]:
+        return init_cnn(key, self)
+
+    def unit_space(self, params):
+        return build_unit_space(self, params)
+
+    def apply(self, params, x, **compute):
+        """Logits ``[b, classes]`` (``cnn_apply``)."""
+        return cnn_apply(params, self, x, **compute)
+
+    def loss(self, logits, y):
+        """Mean cross-entropy of the logits against the labels."""
+        logp = jax.nn.log_softmax(logits)
+        return -jnp.take_along_axis(logp, y[:, None], axis=1).mean()
+
+    def counters(self, logits) -> dict:
+        return {}
+
+    def eval_outputs(self, params, x):
+        return cnn_apply(params, self, x)
+
+    def hits(self, logits, y) -> int:
+        """Images whose largest logit is their label."""
+        return int((np.argmax(np.asarray(logits), -1) == y).sum())
+
+    def flops_from_shapes(self, shapes) -> float:
+        return cnn_flops_from_shapes(shapes, self)
+
+    def unit_scales(self, params) -> Dict[str, np.ndarray]:
+        return extract_bn_scales(params, self)
+
+    def block_unit_masks(self, masks) -> Dict[str, jnp.ndarray]:
+        """Each prunable layer's unit mask, read off its ``bn_g`` mask row
+        (the [width] 0/1 vector the fleet's masks hold there)."""
+        return {n: masks[f"{n}/bn_g"] for n in prunable_layer_names(self)}
+
+    def block_compute(self, unit_masks, blocks) -> Dict[str, float]:
+        return cnn_block_compute(self, unit_masks, blocks)
+
+    def default_task(self, seed: int):
+        from repro.data.synthetic import SyntheticImageTask
+
+        return SyntheticImageTask(
+            num_classes=self.num_classes, image_size=self.image_size,
+            train_size=1280, test_size=512, seed=seed,
+        )
+
 
 def vgg_config(name, plan, num_classes=10, image_size=32) -> CNNConfig:
     return CNNConfig(name=name, kind="vgg", plan=tuple(plan), num_classes=num_classes, image_size=image_size)

@@ -18,6 +18,16 @@ Two execution paths:
 Experts are a prunable AdaptCL unit (whole-expert pruning); the router
 renormalizes over retained experts automatically because pruned experts do
 not exist in the reconfigured weights.
+
+**Held share** (``held_moe_fwd``, DeepSeek-V3 routing, arXiv:2412.19437
+§2.1.2): the layer of one expert-parallel rank.  It routes over all
+``num_experts`` by sigmoid scores, chooses the top ``k`` by score plus a
+correction bias (a constant of the model, never trained), weights the chosen
+experts by their scores renormalised over the top ``k`` and scaled, and
+computes the part of the output that its own ``held`` experts give, dropless:
+every token routed to a held expert is computed, however uneven the routing,
+by a sort and grouped matmuls (``lax.ragged_dot``).  What absent experts add
+is left out.
 """
 from __future__ import annotations
 
@@ -33,7 +43,8 @@ from repro.sharding.specs import current_mesh
 
 from .layers import dense_init, silu
 
-__all__ = ["MoESpec", "init_moe", "moe_fwd", "capacity"]
+__all__ = ["MoESpec", "init_moe", "moe_fwd", "capacity", "HeldMoESpec",
+           "sigmoid_route", "held_moe_fwd"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -189,3 +200,60 @@ def moe_fwd(params, spec: MoESpec, x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.nda
         check_vma=False,
     )(params["w_router"], params["w_gate"], params["w_up"], params["w_down"], shared, x)
     return out, aux
+
+
+# ---------------------------------------------------------------------------
+# held share of a sigmoid-routed layer (DeepSeek-V3 routing, dropless)
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class HeldMoESpec:
+    num_experts: int               # the router's width: every expert of the layer
+    num_experts_per_tok: int
+    first_held: int                # held experts are first_held .. first_held + num_held - 1
+    num_held: int
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+
+
+def sigmoid_route(x, w_router, bias, spec: HeldMoESpec):
+    """``(experts [T, k], weights [T, k])`` of tokens ``x [T, d]``: scores
+    ``s = sigmoid(x Wr)`` in float32 (the product at full float32 precision
+    on every backend), the top ``k`` of ``s + bias``, weighted
+    ``scale * s_i / (sum_topk s_j + 1e-20)`` (or ``scale * s_i`` without
+    renormalisation).  ``bias`` moves the choice, never the weights."""
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32), w_router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(s + bias, spec.num_experts_per_tok)
+    g = jnp.take_along_axis(s, idx, axis=-1)
+    if spec.norm_topk_prob:
+        g = g / (g.sum(-1, keepdims=True) + 1e-20)
+    return idx, g * spec.routed_scaling_factor
+
+
+def held_moe_fwd(p, spec: HeldMoESpec, x, bias):
+    """The held experts' part of the layer for tokens ``x [T, d]``:
+    ``sum over i in top-k and held of g_i * E_i(x)``, with ``E_i`` a SwiGLU
+    (``p["w_gate"], p["w_up"] [n_held, d, f]``, ``p["w_down"] [n_held, f,
+    d]``).  Returns ``(out [T, d], load [n_held])``, the tokens each held
+    expert computed."""
+    T, d = x.shape
+    k, n = spec.num_experts_per_tok, spec.num_held
+    with jax.named_scope("moe.route"):
+        idx, g = sigmoid_route(x, p["router"], bias, spec)
+        local = idx.reshape(T * k) - spec.first_held
+        held = (local >= 0) & (local < n)
+        group = jnp.where(held, local, n)              # n: not held here
+        order = jnp.argsort(group, stable=True)        # held rows first, by expert
+        load = jnp.zeros((n + 1,), jnp.int32).at[group].add(1)[:n]
+        tok = order // k
+        w = jnp.where(held, g.reshape(T * k), 0.0)[order].astype(x.dtype)
+    with jax.named_scope("moe.experts"):
+        # rows past sum(load) (tokens routed elsewhere) come out zero
+        xs = x[tok]
+        h = silu(jax.lax.ragged_dot(xs, p["w_gate"], load)) * jax.lax.ragged_dot(
+            xs, p["w_up"], load)
+        y = jax.lax.ragged_dot(h, p["w_down"], load)
+        out = jnp.zeros((T, d), x.dtype).at[tok].add(y * w[:, None])
+    return out, load

@@ -11,6 +11,8 @@ process may load the TPU library, and only the test worker that runs this
 file should.  The persistent compilation cache is off for these tests: a
 compile for a described chip can be written to it but not read back.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -145,3 +147,32 @@ def test_compile_cache_dir_rule(monkeypatch, tmp_path, env_dir):
         assert set_in_code == (before if env_dir else want)
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+
+
+@pytest.mark.parametrize("workers", [0, 2], ids=["one", "lax_map"])
+def test_held_expert_layer_compiles_for_v5e(one_chip, workers):
+    """The held share of a DeepSeek-V3 expert layer at kanana-2's widths (8
+    of 128 experts of width 768, top-6, 8,192 tokens of width 2,048), forward
+    and backward, alone and mapped over two workers as the lean fleet layout
+    maps it.  The grouped matmuls compile to the instructions
+    ``bench/metrics/expert_ms_per_round.py`` reads: ``ragged-dot-none.N``,
+    three forward and six backward."""
+    from repro.models.moe import HeldMoESpec, held_moe_fwd
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    spec = HeldMoESpec(128, 6, 0, 8, 2.448, True)
+    T, D, F = 8192, 2048, 768
+
+    def loss(p, x):
+        out, load = held_moe_fwd(p, spec, x, jnp.zeros(128))
+        return jnp.sum(out ** 2), load
+
+    grad = jax.grad(loss, argnums=(0, 1), has_aux=True)
+    shapes = {"router": (D, 128), "w_gate": (8, D, F), "w_up": (8, D, F), "w_down": (8, F, D)}
+    lead = (workers,) if workers else ()
+    args = ({k: sds(lead + v) for k, v in shapes.items()}, sds(lead + (T, D)))
+    fn = (lambda p, x: jax.lax.map(lambda a: grad(*a), (p, x))) if workers else grad
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert len(set(re.findall(r"%(ragged-dot-none(?:\.\d+)?) = ", text))) == 9
