@@ -494,9 +494,10 @@ def _build_chunk_fn(trainer, unit_map, base_shapes, flat: UnitFlat, lam,
                 pres_seq, glob_seq, kept_seq, total_seq, quar_seq, cnt_seq)
 
     if mesh is None:
-        # the lean layout hands the parameter stacks and the global model
-        # over to the program: their outputs take the inputs' buffers
-        return jax.jit(chunk, donate_argnums=(0, 3) if lean else ())
+        # the parameter stacks and the global model are handed over to the
+        # program (the host rebinds both): their outputs take the inputs'
+        # buffers
+        return jax.jit(chunk, donate_argnums=(0, 3))
 
     # one lax.scan program PER SHARD: row-stacked args shard over the fleet
     # axis (dim 0 for state, dim 1 for [K, W, ...] per-round tensors), the

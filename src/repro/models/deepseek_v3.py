@@ -31,9 +31,8 @@ apply; the seed-derived orders do.
 Each layer is rematerialised in the backward pass (``jax.checkpoint``), and
 the fleet holds the model in its lean layout (``fleet_layout = "lean"``):
 one worker trains after another, since the grouped matmul has no batched
-form on the TPU and one worker's activations at a time is what fits; masks
-broadcast from per-unit vectors; the chunk program takes over its input
-stacks' buffers.
+form on the TPU and one worker's activations at a time is what fits, and
+masks broadcast from per-unit vectors.
 """
 from __future__ import annotations
 

@@ -22,10 +22,8 @@ Attributes:
     ``"lean"``, for a model whose fleet state fills the device: one worker
     trains after another (a ``lax.map``, so a step's activations are one
     worker's, and operations without a batched form on the device, such as
-    the grouped matmul of a sparse expert layer, run unbatched); masks are
-    per-unit vectors that broadcast against the parameters; and the fused
-    engine's chunk program takes over (donates) the buffers of the parameter
-    stacks and the global model it is given.
+    the grouped matmul of a sparse expert layer, run unbatched), and masks
+    are per-unit vectors that broadcast against the parameters.
 
 Methods:
 

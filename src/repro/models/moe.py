@@ -250,10 +250,19 @@ def held_moe_fwd(p, spec: HeldMoESpec, x, bias):
         tok = order // k
         w = jnp.where(held, g.reshape(T * k), 0.0)[order].astype(x.dtype)
     with jax.named_scope("moe.experts"):
-        # rows past sum(load) (tokens routed elsewhere) come out zero
-        xs = x[tok]
-        h = silu(jax.lax.ragged_dot(xs, p["w_gate"], load)) * jax.lax.ragged_dot(
-            xs, p["w_up"], load)
-        y = jax.lax.ragged_dot(h, p["w_down"], load)
+        # ragged_dot leaves the rows past sum(load) (tokens routed
+        # elsewhere) undefined, in its output and in its transpose for the
+        # left operand: the TPU kernel skips their tiles.  Selecting zero in
+        # those rows of the gathered tokens and of each grouped matmul's
+        # output keeps them out of the result and, through the selects'
+        # transposes, out of every gradient.
+        valid = (jnp.arange(T * k) < load.sum())[:, None]
+
+        def grouped(a, w_):
+            return jnp.where(valid, jax.lax.ragged_dot(a, w_, load), 0)
+
+        xs = jnp.where(valid, x[tok], 0)
+        h = silu(grouped(xs, p["w_gate"])) * grouped(xs, p["w_up"])
+        y = grouped(h, p["w_down"])
         out = jnp.zeros((T, d), x.dtype).at[tok].add(y * w[:, None])
     return out, load

@@ -3,6 +3,7 @@ program's MLA and held-share expert layers against the benchmark's plain
 reference (``bench/models/deepseek_v3.py``), the held shares against the
 uncut layer, dropless routing, the correction bias, and pruned units."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -187,3 +188,66 @@ def test_cig_bnscalor_is_refused_for_want_of_a_per_unit_scale():
                     engine="fused", num_workers=2)
     with pytest.raises(ValueError, match="no per-unit scale"):
         run_simulation(sim)
+
+
+def _poisoned_ragged_dot(real):
+    """``lax.ragged_dot`` with the rows past the groups' sum of its output,
+    and of its transpose for the left operand, set to NaN: what the TPU
+    kernel leaves there is undefined."""
+
+    def poison(a, group_sizes):
+        return jnp.where((jnp.arange(a.shape[0]) < group_sizes.sum())[:, None], a, jnp.nan)
+
+    def ragged_dot(lhs, rhs, group_sizes, **kw):
+        @jax.custom_vjp
+        def f(lhs, rhs):
+            return poison(real(lhs, rhs, group_sizes, **kw), group_sizes)
+
+        def fwd(lhs, rhs):
+            return f(lhs, rhs), (lhs, rhs)
+
+        def bwd(res, g):
+            dl, dr = jax.vjp(lambda a, b: real(a, b, group_sizes, **kw), *res)[1](g)
+            return poison(dl, group_sizes), dr
+
+        f.defvjp(fwd, bwd)
+        return f(lhs, rhs)
+
+    return ragged_dot
+
+
+def test_rows_past_the_groups_stay_out_of_the_output_and_the_gradient(monkeypatch):
+    """Most of the grouped matmuls' rows belong to no held expert; whatever
+    the kernel leaves in them (NaN here) reaches neither the layer's output
+    nor any gradient, which equal the dense reference's."""
+    model = _model()
+    p = _params(model)
+    x = _tokens(model["hidden_size"])
+    mp = _moe_params(p)
+    bias = jnp.zeros(model["n_routed_experts"])
+    monkeypatch.setattr(jax.lax, "ragged_dot", _poisoned_ragged_dot(jax.lax.ragged_dot))
+
+    def prog(mp, x):
+        return held_moe_fwd(mp, _spec(model), x, bias)[0]
+
+    def ref(mp, x):
+        q = dict(p, **{f"L1/experts/{w}": mp[w] for w in ("w_gate", "w_up", "w_down")})
+        q["L1/moe/router"] = mp["router"]
+        return REF._experts(q, "L1/", model, x, HIGHEST)
+
+    ct = _tokens(model["hidden_size"], seed=3)
+
+    @functools.partial(jax.jit, static_argnums=2)
+    def out_and_grads(mp, x, f):
+        out, vjp = jax.vjp(f, mp, x)
+        return out, vjp(ct)
+
+    with jax.default_matmul_precision("highest"):
+        out, grads = out_and_grads(mp, x, prog)
+    _, want = out_and_grads(mp, x, ref)
+    assert int(held_moe_fwd(mp, _spec(model), x, bias)[1].sum()) < x.shape[0] * 3
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref(mp, x)), rtol=1e-5, atol=1e-5)
+    for k in ("router", "w_gate", "w_up", "w_down"):
+        np.testing.assert_allclose(np.asarray(grads[0][k]), np.asarray(want[0][k]),
+                                   rtol=1e-4, atol=1e-5, err_msg=k)
+    np.testing.assert_allclose(np.asarray(grads[1]), np.asarray(want[1]), rtol=1e-4, atol=1e-5)
